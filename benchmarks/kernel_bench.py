@@ -4,7 +4,7 @@ rough cost structure; the roofline numbers for TPU come from the dry-run).
 For each kernel: wall-time vs the pure-jnp oracle at a few shapes, plus
 the analytic VMEM working-set check for the chosen BlockSpecs. Interpret
 mode is orders of magnitude slower than compiled TPU — the timing column
-is for relative comparisons between lookup strategies only.
+says nothing about the chip.
 """
 from __future__ import annotations
 
@@ -31,13 +31,10 @@ def _time(fn, *args, reps=3):
     return (time.perf_counter() - t0) / reps
 
 
-def vmem_working_set(block_rows: int, block_cols: int, depth: int) -> int:
-    """Bytes resident per cr_act block: x block + y block + windows table
-    + onehot intermediate (rows*cols one-hot of depth -> f32)."""
-    blk = block_rows * block_cols * 4
-    table = depth * 4 * 4
-    onehot = block_rows * block_cols * 4  # folded into the dot operand
-    return 2 * blk + table + onehot
+def vmem_working_set(block_rows: int, block_cols: int) -> int:
+    """VMEM bytes resident per cr_act block: the f32 x and y blocks plus
+    the four selected window columns (the table itself sits in SMEM)."""
+    return (2 + 4) * block_rows * block_cols * 4
 
 
 def run(verbose: bool = True) -> dict:
@@ -47,14 +44,11 @@ def run(verbose: bool = True) -> dict:
     for shape in ((256, 512), (1024, 1024)):
         x = jax.random.normal(key, shape, jnp.float32) * 2.0
         t_ref = _time(jax.jit(lambda v: ref.cr_act_ref(v, table)), x)
-        for lookup in ("onehot", "take"):
-            t_k = _time(lambda v, lk=lookup: ops.cr_act(v, lookup=lk), x)
-            err = float(jnp.max(jnp.abs(
-                ops.cr_act(x, lookup=lookup) - ref.cr_act_ref(x, table))))
-            rows.append(dict(kernel="cr_act", scheme="cr_spline",
-                             lookup=lookup, shape=shape,
-                             t_kernel_ms=t_k * 1e3, t_ref_ms=t_ref * 1e3,
-                             max_abs_err=err))
+        t_k = _time(ops.cr_act, x)
+        err = float(jnp.max(jnp.abs(ops.cr_act(x) - ref.cr_act_ref(x, table))))
+        rows.append(dict(kernel="cr_act", scheme="cr_spline", lookup="select",
+                         shape=shape, t_kernel_ms=t_k * 1e3,
+                         t_ref_ms=t_ref * 1e3, max_abs_err=err))
     # fused GLU (distinct keys: wg == wu would mask gate/up operand swaps)
     for (m, d, f) in ((128, 256, 512),):
         kx, kg, ku = jax.random.split(key, 3)
@@ -134,7 +128,7 @@ def run(verbose: bool = True) -> dict:
                              hbm_writes_fused=1, hbm_writes_unfused=3))
 
     ws = vmem_working_set(cr_act_mod.DEFAULT_BLOCK_ROWS,
-                          cr_act_mod.DEFAULT_BLOCK_COLS, 32)
+                          cr_act_mod.DEFAULT_BLOCK_COLS)
     checks = []
     if ws > 16 * 2 ** 20:
         checks.append(f"cr_act default block working set {ws} > 16 MiB VMEM")

@@ -458,19 +458,10 @@ def analyze_hlo(hlo_text: str, profile: bool = False,
 
 
 def xla_cost_analysis(compiled) -> dict:
-    """XLA's own cost analysis as a flat dict, across jax API versions.
-
-    jax <= 0.4.30 returned a dict (or a per-partition list on some
-    backends); 0.4.31+ returns a one-element list of dicts. Normalize to
-    the first partition's dict — the only consumer semantics we rely on
-    (``flops``, ``bytes accessed``) are per-module either way.
-    """
-    ca = compiled.cost_analysis()
-    if ca is None:
-        return {}
-    if isinstance(ca, (list, tuple)):
-        return dict(ca[0]) if ca else {}
-    return dict(ca)
+    """XLA's own cost analysis of a compiled module as a flat dict
+    (``flops``, ``bytes accessed``, ...); empty where the backend has
+    none."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def analyze_compiled(compiled) -> CostTotals:

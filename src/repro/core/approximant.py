@@ -17,7 +17,7 @@ things:
     format. Safe as a jit static argument and closable by kernel bodies.
   * ``build(spec, target)`` — host-side (numpy, float64 fit) parameter
     construction, returning ONE flat float32 2D array per scheme so the
-    parameters ride into kernels as a normal VMEM operand:
+    parameters ride into kernels as one SMEM operand:
         cr_spline  [depth, 4]       CR control-point windows
         pwl        [depth, 2]       segment (value, delta) pairs
         poly       [depth, deg+1]   per-segment Horner coefficients
@@ -25,8 +25,9 @@ things:
   * ``block(v, params, spec)`` — the pure f32 datapath on an array,
     usable both as the NumPy/JAX reference (error analysis, custom-VJP
     recompute) and verbatim inside Pallas kernel bodies (element-wise
-    ops only: gathers via one-hot MXU dot or ``jnp.take``, Horner
-    chains, a Newton reciprocal loop — no divide unit anywhere).
+    ops only: table reads by a select chain in kernels or ``jnp.take``
+    under XLA, Horner chains, a Newton reciprocal loop — no divide unit
+    anywhere).
 
 Registered schemes and their hardware analogues:
 
@@ -54,7 +55,6 @@ import dataclasses
 from functools import lru_cache
 from typing import Callable
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -75,7 +75,7 @@ class ApproxSpec:
     Generalizes the epilogue subsystem's ``TableSpec`` (which is now an
     alias of this class): hashable, so it can be a static argument of
     jitted wrappers and be closed over by Pallas kernel bodies, while
-    the scheme's flat f32 parameter array rides along as a normal VMEM
+    the scheme's flat f32 parameter array rides along as a normal SMEM
     operand. ``period`` is kept as a real field (not a property) so CR
     specs built from a ``SplineTable`` carry the table's own float
     period bit-for-bit.
@@ -320,25 +320,26 @@ def _index_t_split(av, spec: ApproxSpec):
 
 
 def _gather_columns(tableau, ki, lookup: str):
-    """Row-gather of a [depth, C] f32 tableau at int32 indices ``ki``.
+    """Row-gather of a [depth, C] f32 tableau at int32 indices ``ki``
+    (already clipped to [0, depth)). Returns a tuple of C arrays shaped
+    like ``ki``; both lookups pick the same entries exactly.
 
-    ``onehot`` builds a one-hot [.., depth] operand and contracts it
-    with the tableau on the MXU (dense matmul replaces irregular
-    addressing — the TPU-native move for tiny tables, identical to the
-    CR block's lookup). ``take`` is a vector gather (interpret mode /
-    reference; lowers to a select chain for tiny tables on real TPUs).
-    Returns a tuple of C arrays shaped like ``ki``.
+    ``select`` walks the rows: one compare and C selects per row, with
+    the tableau read as scalars. Inside a kernel the tableau is the SMEM
+    ref, so each read is a scalar load and every vector op stays 2-D —
+    Mosaic lowers neither a vector gather nor a [.., depth] one-hot
+    operand at real block sizes. ``take`` is XLA's gather: the jnp
+    reference and the custom-VJP backward.
     """
     depth, ncols = tableau.shape
-    if lookup == "onehot":
-        iota = jax.lax.broadcasted_iota(jnp.int32, ki.shape + (depth,),
-                                        ki.ndim)
-        onehot = (ki[..., None] == iota).astype(jnp.float32)
-        p = jax.lax.dot_general(
-            onehot, tableau,
-            dimension_numbers=(((ki.ndim,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return tuple(p[..., c] for c in range(ncols))
+    if lookup == "select":
+        cols = [jnp.full(ki.shape, tableau[0, c], jnp.float32)
+                for c in range(ncols)]
+        for d in range(1, depth):
+            hit = ki == d
+            cols = [jnp.where(hit, tableau[d, c], col)
+                    for c, col in enumerate(cols)]
+        return tuple(cols)
     if lookup == "take":
         return tuple(jnp.take(tableau[:, c], ki) for c in range(ncols))
     raise ValueError(f"unknown lookup {lookup!r}")
@@ -638,7 +639,7 @@ class PadeRational(Approximant):
     resolution.
 
     Params layout [3, K]: row 0 num coeffs (u^0..), row 1 den coeffs,
-    row 2 [alpha, beta, 0...] — one flat VMEM operand like every other
+    row 2 [alpha, beta, 0...] — one flat SMEM operand like every other
     scheme. Padé targets tanh only; the softplus residual has no odd
     continued fraction, so ``build`` rejects it with a clear error
     (softplus under the rational scheme needs a table-based residual —

@@ -7,6 +7,6 @@ Layout (the spline-epilogue subsystem):
   cr_act.py     thin matmul-free instance (act="tanh") — back-compat
   fused_glu.py  thin GLU instance — back-compat
   ops.py        jit'd public wrappers: padding, leading dims, custom-VJP
-                recompute backward, interpret-mode selection
+                recompute backward
   ref.py        pure-jnp oracles the kernels are validated against
 """

@@ -17,14 +17,11 @@ from .epilogue import (  # noqa: F401  (re-exported: public tuning knobs)
 
 
 def cr_act_2d(x, windows, *, period: float, x_max: float, saturation: float,
-              lookup: str = "onehot",
               block_rows: int = DEFAULT_BLOCK_ROWS,
-              block_cols: int = DEFAULT_BLOCK_COLS,
-              interpret: bool = False):
+              block_cols: int = DEFAULT_BLOCK_COLS):
     """Apply the CR-spline tanh to a 2D array (rows, cols divisible by
     the block shape; `ops.cr_act` handles padding/reshaping)."""
     spec = TableSpec(period=period, depth=windows.shape[0], x_max=x_max,
                      saturation=saturation)
-    return elementwise_2d(x, windows, spec=spec, act="tanh", lookup=lookup,
-                          block_rows=block_rows, block_cols=block_cols,
-                          interpret=interpret)
+    return elementwise_2d(x, windows, spec=spec, act="tanh",
+                          block_rows=block_rows, block_cols=block_cols)

@@ -7,17 +7,18 @@ from it by identities, softplus from a second tiny residual table. This
 module is that unit for Pallas TPU kernels, generalized over the
 Approximant API (``core/approximant.py``): the same epilogue wiring and
 kernel builders run any registered scheme (cr_spline / pwl / poly /
-rational), with the scheme's flat f32 params as a generic VMEM operand.
+rational), with the scheme's flat f32 params as a generic SMEM operand.
 It owns:
 
   * ``TableSpec`` — now an alias of ``approximant.ApproxSpec``, the
     hashable static geometry (scheme, depth/degree, domain, symmetry,
     fixed-point format) kernels close over while the params array rides
-    along as a normal VMEM operand;
+    along whole in SMEM, read as scalars;
   * ``_cr_tanh_block`` — the paper's Fig. 2/3 datapath on a 2D f32
     block (index/t split, 4-tap basis MAC, saturation, optional
-    odd-symmetry sign fixup) with both LUT-lookup strategies
-    (onehot-MXU / take). This is the single authoritative CR block —
+    odd-symmetry sign fixup), reading its window LUT through
+    ``approximant._gather_columns`` (a select chain inside kernels, an
+    XLA gather outside). This is the single authoritative CR block —
     the approximant registry's ``cr_spline`` scheme delegates here;
     non-CR blocks live with their schemes in ``core/approximant.py``;
   * the composable epilogues ``tanh | sigmoid | silu | gelu_tanh |
@@ -33,6 +34,10 @@ It owns:
       - ``glu_2d``: GLU epilogue — (M, N, K) matmul grid with two f32
         VMEM accumulators, epilogue fired on the gate accumulator at
         the last K step (``fused_glu_2d`` is an instance).
+
+Both builders compile their kernel when the program is lowered for a
+TPU and interpret it when it is lowered for the CPU (``_on_platform``);
+any other platform fails to lower.
 
 Downstream, ``ops.py`` wraps these with padding/jit, the
 ``ActivationEngine`` dispatches every ``use_kernel=True`` nonlinearity
@@ -60,7 +65,6 @@ from repro.core.approximant import ApproxSpec
 SQRT_2_OVER_PI = math.sqrt(2.0 / math.pi)
 
 EPILOGUES = ("tanh", "sigmoid", "silu", "gelu_tanh", "softplus")
-LOOKUPS = ("onehot", "take")
 
 DEFAULT_BLOCK_ROWS = 32
 DEFAULT_BLOCK_COLS = 512
@@ -117,20 +121,16 @@ def _basis_weights_f32(t):
     return w0, w1, w2, w3
 
 
-def _cr_tanh_block(v, win, *, spec: TableSpec, lookup: str = "onehot",
+def _cr_tanh_block(v, win, *, spec: TableSpec, lookup: str = "take",
                    odd: bool = True):
     """CR-spline interpolation of a 2D f32 block — the shared datapath.
 
     TPU adaptation of the paper's Fig. 2/3: index/t split is a float
     multiply + floor (hardware: bit slice), the basis polynomials run in
     Horner form on the VPU lanes, the 4-tap MAC is a lane-wise FMA chain.
-
-    ``lookup`` selects how the [depth, 4] window LUT is addressed:
-      onehot  indices -> one-hot [*, depth] -> dot with the table on the
-              MXU. Dense matmul replaces irregular addressing — the
-              TPU-native move for tiny tables.
-      take    vector gather from VMEM (fine in interpret mode; lowers to
-              a select chain for tiny tables on real TPUs).
+    The [depth, 4] window LUT is read through
+    ``approximant._gather_columns``: ``lookup="select"`` inside kernels
+    (``win`` is then the SMEM ref), ``"take"`` under XLA.
 
     ``odd=True`` evaluates on |v| and restores the sign (tanh family);
     ``odd=False`` evaluates the table at v directly (softplus residual —
@@ -140,25 +140,8 @@ def _cr_tanh_block(v, win, *, spec: TableSpec, lookup: str = "onehot",
     u = av * spec.inv_period
     k = jnp.clip(jnp.floor(u), 0.0, spec.depth - 1.0)
     t = u - k                                        # in [0, 1)
-    ki = k.astype(jnp.int32)
-
-    if lookup == "onehot":
-        bm, bn = v.shape
-        iota = jax.lax.broadcasted_iota(jnp.int32, (bm, bn, spec.depth), 2)
-        onehot = (ki[..., None] == iota).astype(jnp.float32)
-        # [bm, bn, depth] . [depth, 4] on the MXU
-        p = jax.lax.dot_general(
-            onehot, win, dimension_numbers=(((2,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # [bm, bn, 4]
-        p0, p1, p2, p3 = p[..., 0], p[..., 1], p[..., 2], p[..., 3]
-    elif lookup == "take":
-        p0 = jnp.take(win[:, 0], ki)
-        p1 = jnp.take(win[:, 1], ki)
-        p2 = jnp.take(win[:, 2], ki)
-        p3 = jnp.take(win[:, 3], ki)
-    else:
-        raise ValueError(f"unknown lookup {lookup!r}")
-
+    p0, p1, p2, p3 = approximant._gather_columns(win, k.astype(jnp.int32),
+                                                 lookup)
     w0, w1, w2, w3 = _basis_weights_f32(t)
     y = p0 * w0 + p1 * w1 + p2 * w2 + p3 * w3        # the 4-tap MAC
     y = jnp.where(av >= spec.x_max, jnp.float32(spec.saturation), y)
@@ -180,7 +163,7 @@ def _block_for(spec: ApproxSpec, lookup: str):
     return blk
 
 
-def make_epilogue(act: str, spec: TableSpec, lookup: str = "onehot"):
+def make_epilogue(act: str, spec: TableSpec, lookup: str = "take"):
     """Build the f32-block epilogue ``fn(v, params) -> y`` for ``act``.
 
     All tanh-derived epilogues reuse ONE approximant evaluation per
@@ -209,16 +192,20 @@ def make_epilogue(act: str, spec: TableSpec, lookup: str = "onehot"):
     raise ValueError(f"unknown epilogue {act!r}")
 
 
-# ---------------------------------------------------------------------------
-# kernel builder 1: matmul-free epilogue (element-wise over 2D blocks)
-# ---------------------------------------------------------------------------
+def _on_platform(call, *args):
+    """``call(*args, interpret=...)`` decided by the platform the program
+    is lowered for, not by the process's default backend: compiled by
+    Mosaic for a TPU, interpreted for the CPU, and refused (no branch)
+    anywhere else. A TPU compile rehearsed from a CPU-only process
+    therefore holds the real kernel."""
+    return jax.lax.platform_dependent(
+        *args, cpu=functools.partial(call, interpret=True),
+        tpu=functools.partial(call, interpret=False))
 
-def _elementwise_kernel(x_ref, win_ref, o_ref, *, act: str, spec: TableSpec,
-                        lookup: str):
-    epi = make_epilogue(act, spec, lookup)
-    x = x_ref[...].astype(jnp.float32)               # [bm, bn]
-    y = epi(x, win_ref[...].astype(jnp.float32))
-    o_ref[...] = y.astype(o_ref.dtype)
+
+# The params ride whole in SMEM: the kernels read them as scalars
+# (``lookup="select"``), so every vector op stays a 2-D VPU op.
+_PARAMS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
 
 
 def _check_params(params, spec: ApproxSpec):
@@ -226,11 +213,19 @@ def _check_params(params, spec: ApproxSpec):
     assert tuple(params.shape) == tuple(expected), (params.shape, spec)
 
 
+# ---------------------------------------------------------------------------
+# kernel builder 1: matmul-free epilogue (element-wise over 2D blocks)
+# ---------------------------------------------------------------------------
+
+def _elementwise_kernel(x_ref, win_ref, o_ref, *, act: str, spec: TableSpec):
+    epi = make_epilogue(act, spec, "select")
+    x = x_ref[...].astype(jnp.float32)               # [bm, bn]
+    o_ref[...] = epi(x, win_ref).astype(o_ref.dtype)
+
+
 def elementwise_2d(x, params, *, spec: TableSpec, act: str = "tanh",
-                   lookup: str = "onehot",
                    block_rows: int = DEFAULT_BLOCK_ROWS,
-                   block_cols: int = DEFAULT_BLOCK_COLS,
-                   interpret: bool = False):
+                   block_cols: int = DEFAULT_BLOCK_COLS):
     """Apply one approximant epilogue to a 2D array in a single
     pallas_call.
 
@@ -238,25 +233,25 @@ def elementwise_2d(x, params, *, spec: TableSpec, act: str = "tanh",
     (lane width), block_rows a multiple of 8 (sublane). Dims must divide
     by the block shape — ``ops.act`` handles padding/reshaping.
     ``params`` is the scheme's flat f32 array (CR windows, PWL segment
-    pairs, poly coefficients, Padé rows), whole-array resident in VMEM.
+    pairs, poly coefficients, Padé rows), whole-array resident in SMEM.
     """
     rows, cols = x.shape
     _check_params(params, spec)
     assert rows % block_rows == 0 and cols % block_cols == 0, (x.shape,)
-    grid = (rows // block_rows, cols // block_cols)
-    kernel = functools.partial(_elementwise_kernel, act=act, spec=spec,
-                               lookup=lookup)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-            pl.BlockSpec(params.shape, lambda i, j: (0, 0)),  # whole LUT in VMEM
-        ],
-        out_specs=pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
-        interpret=interpret,
-    )(x, params)
+    kernel = functools.partial(_elementwise_kernel, act=act, spec=spec)
+    blk = pl.BlockSpec((block_rows, block_cols), lambda i, j: (i, j))
+
+    def call(x, params, *, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(rows // block_rows, cols // block_cols),
+            in_specs=[blk, _PARAMS_SPEC],
+            out_specs=blk,
+            out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+            interpret=interpret,
+        )(x, params)
+
+    return _on_platform(call, x, params)
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +259,7 @@ def elementwise_2d(x, params, *, spec: TableSpec, act: str = "tanh",
 # ---------------------------------------------------------------------------
 
 def _glu_kernel(x_ref, wg_ref, wu_ref, win_ref, o_ref, gate_acc, up_acc, *,
-                n_k: int, act: str, spec: TableSpec, lookup: str):
+                n_k: int, act: str, spec: TableSpec):
     k_step = pl.program_id(2)
 
     @pl.when(k_step == 0)
@@ -280,16 +275,13 @@ def _glu_kernel(x_ref, wg_ref, wu_ref, win_ref, o_ref, gate_acc, up_acc, *,
 
     @pl.when(k_step == n_k - 1)
     def _done():
-        epi = make_epilogue(act, spec, lookup)
-        win = win_ref[...].astype(jnp.float32)
-        y = epi(gate_acc[...], win) * up_acc[...]
+        epi = make_epilogue(act, spec, "select")
+        y = epi(gate_acc[...], win_ref) * up_acc[...]
         o_ref[...] = y.astype(o_ref.dtype)
 
 
 def glu_2d(x, w_gate, w_up, params, *, spec: TableSpec, act: str = "silu",
-           lookup: str = "onehot",
-           block_m: int = 128, block_n: int = 128, block_k: int = 512,
-           interpret: bool = False):
+           block_m: int = 128, block_n: int = 128, block_k: int = 512):
     """out[M,N] = epilogue(x[M,K] @ w_gate[K,N]) * (x @ w_up) — the TPU
     embodiment of the paper's deployment: the activation unit reads the
     MAC-array accumulator directly, so the gate projection never
@@ -307,22 +299,24 @@ def glu_2d(x, w_gate, w_up, params, *, spec: TableSpec, act: str = "silu",
         x.shape, w_gate.shape)
     _check_params(params, spec)
     n_k = k // block_k
-    kernel = functools.partial(_glu_kernel, n_k=n_k, act=act, spec=spec,
-                               lookup=lookup)
-    return pl.pallas_call(
-        kernel,
-        grid=(m // block_m, n // block_n, n_k),
-        in_specs=[
-            pl.BlockSpec((block_m, block_k), lambda i, j, s: (i, s)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, s: (s, j)),
-            pl.BlockSpec((block_k, block_n), lambda i, j, s: (s, j)),
-            pl.BlockSpec(params.shape, lambda i, j, s: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((block_m, block_n), jnp.float32),
-            pltpu.VMEM((block_m, block_n), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, w_gate, w_up, params)
+    kernel = functools.partial(_glu_kernel, n_k=n_k, act=act, spec=spec)
+    w_blk = pl.BlockSpec((block_k, block_n), lambda i, j, s: (s, j))
+
+    def call(x, w_gate, w_up, params, *, interpret):
+        return pl.pallas_call(
+            kernel,
+            grid=(m // block_m, n // block_n, n_k),
+            in_specs=[
+                pl.BlockSpec((block_m, block_k), lambda i, j, s: (i, s)),
+                w_blk, w_blk, _PARAMS_SPEC,
+            ],
+            out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, s: (i, j)),
+            out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+            scratch_shapes=[
+                pltpu.VMEM((block_m, block_n), jnp.float32),
+                pltpu.VMEM((block_m, block_n), jnp.float32),
+            ],
+            interpret=interpret,
+        )(x, w_gate, w_up, params)
+
+    return _on_platform(call, x, w_gate, w_up, params)

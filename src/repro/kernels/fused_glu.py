@@ -23,13 +23,10 @@ from .epilogue import (  # noqa: F401  (re-exported: shared datapath)
 
 def fused_glu_2d(x, w_gate, w_up, windows, *, period: float, x_max: float,
                  saturation: float, act: str = "silu",
-                 lookup: str = "onehot",
-                 block_m: int = 128, block_n: int = 128, block_k: int = 512,
-                 interpret: bool = False):
+                 block_m: int = 128, block_n: int = 128, block_k: int = 512):
     """out[M,N] = act_cr(x[M,K] @ w_gate[K,N]) * (x @ w_up). Dims must be
     divisible by the block shape (`ops.fused_glu` pads)."""
     spec = TableSpec(period=period, depth=windows.shape[0], x_max=x_max,
                      saturation=saturation)
-    return glu_2d(x, w_gate, w_up, windows, spec=spec, act=act, lookup=lookup,
-                  block_m=block_m, block_n=block_n, block_k=block_k,
-                  interpret=interpret)
+    return glu_2d(x, w_gate, w_up, windows, spec=spec, act=act,
+                  block_m=block_m, block_n=block_n, block_k=block_k)

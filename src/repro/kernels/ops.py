@@ -1,9 +1,10 @@
 """jit'd public wrappers around the Pallas epilogue kernels.
 
 Handles: arbitrary leading dims (flattened to rows), padding to block
-multiples, dtype pass-through, approximant-scheme selection per
-epilogue, and interpret-mode selection (CPU backend executes kernels in
-interpret mode; TPU compiles them).
+multiples, dtype pass-through and approximant-scheme selection per
+epilogue. Whether a kernel is compiled (TPU) or interpreted (CPU) is
+decided by the platform the program is lowered for, inside the kernel
+builders (``epilogue._on_platform``).
 
 Public surface:
   act(x, name, method=...)  one-pallas_call element-wise epilogue (any
@@ -35,10 +36,6 @@ from repro.core.activations import tanh_table
 from . import epilogue as epi
 
 EPILOGUES = epi.EPILOGUES
-
-
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(v: int, m: int) -> int:
@@ -75,11 +72,9 @@ def _resolve_spec_params(act: str, table: cr.SplineTable | None,
 # element-wise epilogues
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("spec", "act", "lookup",
-                                             "interpret", "block_rows",
+@functools.partial(jax.jit, static_argnames=("spec", "act", "block_rows",
                                              "block_cols"))
-def _act_impl(x, windows, *, spec, act, lookup, interpret, block_rows,
-              block_cols):
+def _act_impl(x, windows, *, spec, act, block_rows, block_cols):
     orig_shape = x.shape
     cols = orig_shape[-1] if orig_shape else 1   # 0-d: single element
     rows = int(np.prod(orig_shape[:-1])) if len(orig_shape) > 1 else 1
@@ -90,15 +85,14 @@ def _act_impl(x, windows, *, spec, act, lookup, interpret, block_rows,
     pr, pc = _pad_to(rows, br), _pad_to(cols, bc)
     if (pr, pc) != (rows, cols):
         x2 = jnp.pad(x2, ((0, pr - rows), (0, pc - cols)))
-    y = epi.elementwise_2d(x2, windows, spec=spec, act=act, lookup=lookup,
-                           block_rows=br, block_cols=bc, interpret=interpret)
+    y = epi.elementwise_2d(x2, windows, spec=spec, act=act,
+                           block_rows=br, block_cols=bc)
     return y[:rows, :cols].reshape(orig_shape)
 
 
 def _act_ref_math(static, x, windows):
-    """jnp recompute of the epilogue for the backward pass. ``take``
-    lookup is numerically identical to ``onehot`` (a one-hot f32 dot
-    selects the same window values exactly) and shape-agnostic."""
+    """jnp recompute of the epilogue for the backward pass. Its ``take``
+    lookup picks the same table entries as the kernels' select chain."""
     spec, act_name = static[0], static[1]
     fn = epi.make_epilogue(act_name, spec, "take")
     return fn(x.astype(jnp.float32), windows).astype(x.dtype)
@@ -106,9 +100,9 @@ def _act_ref_math(static, x, windows):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _act_core(static, x, windows):
-    spec, act_name, lookup, interpret, br, bc = static
-    return _act_impl(x, windows, spec=spec, act=act_name, lookup=lookup,
-                     interpret=interpret, block_rows=br, block_cols=bc)
+    spec, act_name, br, bc = static
+    return _act_impl(x, windows, spec=spec, act=act_name, block_rows=br,
+                     block_cols=bc)
 
 
 def _act_core_fwd(static, x, windows):
@@ -127,7 +121,6 @@ _act_core.defvjp(_act_core_fwd, _act_core_bwd)
 def act(x, name: str = "tanh", table: cr.SplineTable | None = None, *,
         method: str | None = None, spec: epi.ApproxSpec | None = None,
         params=None, depth: int = 32, degree: int = 3, x_max: float = 4.0,
-        lookup: str = "onehot", interpret: bool | None = None,
         block_rows: int = epi.DEFAULT_BLOCK_ROWS,
         block_cols: int = epi.DEFAULT_BLOCK_COLS):
     """Any approximant epilogue as a SINGLE Pallas kernel launch.
@@ -140,37 +133,32 @@ def act(x, name: str = "tanh", table: cr.SplineTable | None = None, *,
     ``epilogue.table_for``). ``params`` overrides the registry-built
     parameter array with a traced one (the trainable model leaf) —
     same shape, same spec, and it rides into the kernel as the normal
-    VMEM operand, so gradients flow through the custom-VJP recompute."""
+    SMEM operand, so gradients flow through the custom-VJP recompute."""
     spec, p = _resolve_spec_params(name, table, method, spec, depth,
                                    degree, x_max)
     if params is not None:
         p = jnp.asarray(params, jnp.float32)
-    if interpret is None:
-        interpret = _interpret_default()
-    static = (spec, name, lookup, interpret, block_rows, block_cols)
+    static = (spec, name, block_rows, block_cols)
     return _act_core(static, x, p)
 
 
-def cr_act(x, table: cr.SplineTable | None = None, *, lookup: str = "onehot",
-           interpret: bool | None = None,
+def cr_act(x, table: cr.SplineTable | None = None, *,
            block_rows: int = epi.DEFAULT_BLOCK_ROWS,
            block_cols: int = epi.DEFAULT_BLOCK_COLS):
     """CR-spline tanh via the Pallas kernel. ``table`` defaults to the
     paper's flagship (x_max=4, depth=32)."""
-    return act(x, "tanh", table or tanh_table(4.0, 32), lookup=lookup,
-               interpret=interpret, block_rows=block_rows,
-               block_cols=block_cols)
+    return act(x, "tanh", table or tanh_table(4.0, 32),
+               block_rows=block_rows, block_cols=block_cols)
 
 
 # ---------------------------------------------------------------------------
 # fused GLU
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.jit, static_argnames=("spec", "act", "lookup",
-                                             "interpret", "block_m",
+@functools.partial(jax.jit, static_argnames=("spec", "act", "block_m",
                                              "block_n", "block_k"))
-def _fused_glu_impl(x, w_gate, w_up, windows, *, spec, act, lookup, interpret,
-                    block_m, block_n, block_k):
+def _fused_glu_impl(x, w_gate, w_up, windows, *, spec, act, block_m, block_n,
+                    block_k):
     orig_shape = x.shape
     k = orig_shape[-1]
     m = int(np.prod(orig_shape[:-1])) if len(orig_shape) > 1 else 1
@@ -186,8 +174,8 @@ def _fused_glu_impl(x, w_gate, w_up, windows, *, spec, act, lookup, interpret,
     if (pk, pn) != (k, n):
         wg = jnp.pad(wg, ((0, pk - k), (0, pn - n)))
         wu = jnp.pad(wu, ((0, pk - k), (0, pn - n)))
-    y = epi.glu_2d(x2, wg, wu, windows, spec=spec, act=act, lookup=lookup,
-                   block_m=bm, block_n=bn, block_k=bk, interpret=interpret)
+    y = epi.glu_2d(x2, wg, wu, windows, spec=spec, act=act,
+                   block_m=bm, block_n=bn, block_k=bk)
     return y[:m, :n].reshape(orig_shape[:-1] + (n,))
 
 
@@ -204,9 +192,8 @@ def _fused_glu_ref_math(static, x, w_gate, w_up, windows):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _fused_glu_core(static, x, w_gate, w_up, windows):
-    spec, act_name, lookup, interpret, bm, bn, bk = static
+    spec, act_name, bm, bn, bk = static
     return _fused_glu_impl(x, w_gate, w_up, windows, spec=spec, act=act_name,
-                           lookup=lookup, interpret=interpret,
                            block_m=bm, block_n=bn, block_k=bk)
 
 
@@ -229,7 +216,6 @@ def fused_glu(x, w_gate, w_up, table: cr.SplineTable | None = None, *,
               act: str = "silu", method: str | None = None,
               spec: epi.ApproxSpec | None = None, params=None,
               depth: int = 32, degree: int = 3, x_max: float = 4.0,
-              lookup: str = "onehot", interpret: bool | None = None,
               block_m: int = 128, block_n: int = 128, block_k: int = 512):
     """epilogue(x @ w_gate) * (x @ w_up) in one fused Pallas kernel,
     under any registered approximant scheme (selection as in ``act``;
@@ -239,7 +225,5 @@ def fused_glu(x, w_gate, w_up, table: cr.SplineTable | None = None, *,
                                    degree, x_max)
     if params is not None:
         p = jnp.asarray(params, jnp.float32)
-    if interpret is None:
-        interpret = _interpret_default()
-    static = (spec, act, lookup, interpret, block_m, block_n, block_k)
+    static = (spec, act, block_m, block_n, block_k)
     return _fused_glu_core(static, x, w_gate, w_up, p)

@@ -15,14 +15,9 @@ import jax
 
 
 def make_mesh_auto(shape: tuple, axes: tuple):
-    """jax.make_mesh with Auto axis types, across jax versions: 0.4.x has
-    no jax.sharding.AxisType (all axes are implicitly Auto); newer jax
-    accepts it explicitly."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

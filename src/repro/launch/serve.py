@@ -29,6 +29,7 @@ import numpy as np
 from repro.configs import registry
 from repro.data import DataConfig, SyntheticPipeline
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.parallel import partition as part
@@ -41,7 +42,7 @@ class ServeStats:
     prefill_s: float
     decode_s: float
     n_prompts: int
-    prompt_len: int
+    prompt_len: int         # longest prompt of the batch
     generated: int          # tokens emitted per prompt (incl. prefill sample)
     decode_steps: int       # sequential decode steps actually run
     decode_tokens: int      # PLANE tokens emitted by decode steps: a
@@ -142,7 +143,9 @@ def serve_batch(cfg, params, prompts, gen_tokens: int, *,
                 rules: dict | None = None, cache: str = "paged",
                 page_size: int = 16, prefix_cache: bool = True,
                 chunk_prefill: int = 0, token_budget: int | None = None):
-    """prompts: int32 [B, S(, K)]. Returns (tokens [B, gen(, K)], stats).
+    """prompts: int32 [B, S(, K)], or a sequence of B prompts of their
+    own lengths (S is then the longest). Returns (tokens [B, gen(, K)],
+    stats).
 
     Always constructs a continuous-batching ServeEngine (batched-bucket
     admission, in-jit scan decode; `mesh` shards its datapath;
@@ -155,7 +158,7 @@ def serve_batch(cfg, params, prompts, gen_tokens: int, *,
     With `eos_id`, rows that emit it (codebook 0 for K > 1) stop early;
     every returned row is right-padded with 0 to gen_tokens, so
     completions of ragged lengths still stack into one block."""
-    B, S = prompts.shape[0], prompts.shape[1]
+    B, S = len(prompts), max(len(p) for p in prompts)
     max_len = S + gen_tokens
     if capacity is not None:
         # an earlier version silently rerouted any explicit capacity to
@@ -302,6 +305,7 @@ def main(argv=None):
                         "replica range (implies the router path)")
     p.add_argument("--json", default=None, help="write stats JSON here")
     args = p.parse_args(argv)
+    use_compile_cache()
 
     cfg = registry.get(args.arch, smoke=args.smoke)
     if args.activation:
@@ -315,11 +319,15 @@ def main(argv=None):
         cfg = act_impl_of(cfg, args.act_impl,
                           use_kernel=True if args.act_impl_kernel else None)
     mesh = make_host_mesh(1, args.model_parallel)
-    if args.model_parallel > 1 and dict(mesh.shape).get("model", 1) < 2:
+    if dict(mesh.shape)["model"] < args.model_parallel:
+        devices = jax.devices()
+        hint = (" (force host devices via XLA_FLAGS="
+                "--xla_force_host_platform_device_count=N)"
+                if devices[0].platform == "cpu" else "")
         raise SystemExit(
             f"--model-parallel {args.model_parallel} needs that many "
-            f"devices; found {len(jax.devices())} (force host devices via "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
+            f"devices; found {len(devices)} {devices[0].platform} "
+            f"device(s) ({devices[0].device_kind}){hint}")
     act_tag = cfg.activation.tag()
     if cfg.act_impl:
         act_tag += f" (act_impl={cfg.act_impl})"

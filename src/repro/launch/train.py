@@ -26,6 +26,7 @@ from repro.configs import registry
 from repro.data import DataConfig, SyntheticPipeline
 from repro.ft import FTConfig, TrainDriver
 from repro.launch import steps as steps_mod
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.optim import adamw, compress
@@ -78,6 +79,7 @@ def build_parser():
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     cfg = registry.get(args.arch, smoke=args.smoke)
     if args.activation:
         cfg = dataclasses.replace(

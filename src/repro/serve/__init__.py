@@ -16,8 +16,7 @@ backpressure, and stats-driven autoscaling.
 """
 from .engine import (EngineConfig, EngineStats, ServeEngine, StatsWindow,
                      sample_tokens, sample_tokens_indexed)
-from .replica import (InProcessReplica, ProcessReplica, Replica,
-                      ReplicaLoad, ReplicaSpec)
+from .replica import InProcessReplica, Replica, ReplicaLoad
 from .router import (AutoscaleConfig, Autoscaler, AutoscaleSignal,
                      Router, RouterConfig, RouterStats, dispatch_cost)
 from .scheduler import (Completion, FifoScheduler, Request, StepPlan,
@@ -32,10 +31,8 @@ __all__ = [
     "EngineStats",
     "FifoScheduler",
     "InProcessReplica",
-    "ProcessReplica",
     "Replica",
     "ReplicaLoad",
-    "ReplicaSpec",
     "Request",
     "Router",
     "RouterConfig",

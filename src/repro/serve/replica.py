@@ -12,28 +12,18 @@ engine lives:
     pending -> bool
     close()
 
-`InProcessReplica` wraps an engine in the router's own process — the
-baseline mode, stepped round-robin by the router; every replica shares
-the host's devices (and, in-process, the same `params` arrays — no
-copies). `ProcessReplica` runs the engine in a spawned worker process
-behind the SAME protocol: the worker owns its own jax runtime, builds
-its model from a `ReplicaSpec` (never pickles params), and may lay its
-own TP mesh over its own devices — which is exactly why the mode
-exists: tensor-parallel meshes stay *per-replica*, the router stays a
-plain event loop. RPC is deliberately synchronous (one tagged
-request/reply per call); pipelining worker steps behind the router's
-back would trade determinism for latency this tier doesn't need yet.
+`InProcessReplica` wraps an engine in the router's own process, stepped
+round-robin by the router; every replica shares the host's devices (and
+the same `params` arrays — no copies). A replica lives in the router's
+process because a chip belongs to one process at a time: a spawned
+worker could not reach a chip its parent already holds.
 """
 from __future__ import annotations
 
 import dataclasses
-import multiprocessing as mp
 from typing import Protocol
 
-import numpy as np
-
-from .engine import EngineConfig, EngineStats, ServeEngine
-from .scheduler import Completion
+from .engine import EngineStats, ServeEngine
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,135 +108,3 @@ class InProcessReplica:
 
     def close(self) -> None:
         pass
-
-
-@dataclasses.dataclass(frozen=True)
-class ReplicaSpec:
-    """Everything a worker process needs to build its engine itself.
-    Params are MATERIALIZED in the worker (never pickled across the
-    pipe); `model_parallel > 1` lays a TP mesh over the worker's own
-    devices — per-replica, invisible to the router."""
-    arch: str = "qwen3-0.6b"
-    smoke: bool = True
-    seed: int = 0
-    bf16: bool = True
-    model_parallel: int = 1
-    engine: dict = dataclasses.field(default_factory=dict)  # EngineConfig kwargs
-
-
-def _worker_main(conn, spec: ReplicaSpec) -> None:
-    """Synchronous RPC loop around one engine (spawned process)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.configs import registry
-    from repro.models import model as M
-
-    cfg = registry.get(spec.arch, smoke=spec.smoke)
-    mesh = None
-    if spec.model_parallel > 1:
-        from repro.launch.mesh import make_host_mesh
-        mesh = make_host_mesh(1, spec.model_parallel)
-    params, _ = M.materialize_params(cfg, seed=spec.seed)
-    if spec.bf16:
-        params = jax.tree.map(
-            lambda a: a.astype(jnp.bfloat16)
-            if jnp.issubdtype(a.dtype, jnp.floating) else a, params)
-    engine = ServeEngine(cfg, params, EngineConfig(**spec.engine), mesh=mesh)
-    conn.send(("ready", None))
-    while True:
-        op, payload = conn.recv()
-        if op == "submit":
-            uid = engine.submit(payload["tokens"], payload["max_new"],
-                                temperature=payload["temperature"],
-                                eos_id=payload["eos_id"], uid=payload["uid"],
-                                arrival_s=payload["arrival_s"])
-            conn.send(("submit", uid))
-        elif op == "step":
-            conn.send(("step", engine.step()))
-        elif op == "poll":
-            done, engine.completions = engine.completions, []
-            conn.send(("poll", [dataclasses.asdict(c) for c in done]))
-        elif op == "load":
-            conn.send(("load", dataclasses.asdict(_load_of(engine))))
-        elif op == "stats":
-            conn.send(("stats", dataclasses.asdict(engine.snapshot())))
-        elif op == "close":
-            conn.send(("close", None))
-            return
-        else:                                   # defensive: unknown op
-            conn.send(("error", f"unknown op {op!r}"))
-
-
-class ProcessReplica:
-    """A ServeEngine in a spawned worker process, same protocol as
-    InProcessReplica. `spawn` (not fork): the parent's jax runtime has
-    live threads a fork would corrupt; the worker imports jax fresh.
-
-    `pending` is mirrored host-side (submits minus polled completions)
-    so the router's idle checks cost no RPC."""
-
-    def __init__(self, spec: ReplicaSpec):
-        ctx = mp.get_context("spawn")
-        self._conn, child = ctx.Pipe()
-        self._proc = ctx.Process(target=_worker_main, args=(child, spec),
-                                 daemon=True)
-        self._proc.start()
-        child.close()
-        self._in_flight = 0
-        self._closed = False
-        tag, _ = self._conn.recv()              # blocks until model built
-        assert tag == "ready", tag
-
-    def _rpc(self, op: str, payload=None):
-        self._conn.send((op, payload))
-        tag, val = self._conn.recv()
-        if tag == "error":
-            raise RuntimeError(f"replica worker: {val}")
-        assert tag == op, (tag, op)
-        return val
-
-    def submit(self, prompt_tokens, max_new: int, *, temperature: float = 0.0,
-               eos_id=None, uid=None, arrival_s=None) -> int:
-        arr = np.asarray(prompt_tokens)
-        if arr.ndim == 2:       # [S, K] multi-codebook: keep the planes
-            toks = [tuple(int(x) for x in row) for row in arr]
-        else:
-            toks = [int(t) for t in arr.reshape(-1)]
-        uid = self._rpc("submit", {
-            "tokens": toks, "max_new": int(max_new),
-            "temperature": float(temperature), "eos_id": eos_id,
-            "uid": uid, "arrival_s": arrival_s})
-        self._in_flight += 1
-        return uid
-
-    def step(self) -> bool:
-        return self._rpc("step")
-
-    def poll(self) -> list:
-        done = [Completion(**d) for d in self._rpc("poll")]
-        self._in_flight -= len(done)
-        return done
-
-    def load(self) -> ReplicaLoad:
-        return ReplicaLoad(**self._rpc("load"))
-
-    def stats(self) -> EngineStats:
-        return EngineStats(**self._rpc("stats"))
-
-    @property
-    def pending(self) -> bool:
-        return self._in_flight > 0
-
-    def close(self) -> None:
-        if self._closed:
-            return
-        self._closed = True
-        try:
-            self._rpc("close")
-        except (BrokenPipeError, EOFError, OSError):
-            pass
-        self._conn.close()
-        self._proc.join(timeout=10)
-        if self._proc.is_alive():
-            self._proc.terminate()

@@ -17,8 +17,6 @@ import pytest
 def pytest_configure(config):
     config.addinivalue_line(
         "markers", "x64: enables global float64 for paper-table precision")
-    config.addinivalue_line(
-        "markers", "slow: spawns worker processes / builds models repeatedly")
 
 
 try:

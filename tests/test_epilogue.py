@@ -1,7 +1,7 @@
 """The spline-epilogue subsystem, kernel to model.
 
 Three layers of guarantees:
-  * kernel vs oracle: every epilogue x lookup strategy x odd shapes
+  * kernel vs oracle: every epilogue x block shape x odd shapes
     (exercising ops.py's padding path), element-wise and fused-GLU;
   * engine: with ``use_kernel=True`` every nonlinearity lowers to
     exactly ONE pallas_call (jaxpr inspection) and agrees with the jnp
@@ -13,6 +13,7 @@ Three layers of guarantees:
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -31,11 +32,19 @@ def rand(shape, dtype=jnp.float32, scale=6.0, seed=0):
 
 
 def count_pallas_calls(jaxpr) -> int:
-    """Recursively count pallas_call eqns (through pjit/custom_vjp/...)."""
+    """Recursively count the pallas_call eqns one execution runs (through
+    pjit/custom_vjp/...). A cond runs one branch, so it counts as its
+    busiest branch: the kernel builders put the compiled and the
+    interpreted launch of the same kernel in the two branches of a
+    platform-dependent cond."""
     n = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             n += 1
+        elif eqn.primitive.name == "cond":
+            n += max(count_pallas_calls(b.jaxpr)
+                     for b in eqn.params["branches"])
+            continue
         for v in eqn.params.values():
             for sub in _subjaxprs_of(v):
                 n += count_pallas_calls(sub)
@@ -45,9 +54,9 @@ def count_pallas_calls(jaxpr) -> int:
 def _subjaxprs_of(v):
     vals = v if isinstance(v, (tuple, list)) else (v,)
     for e in vals:
-        if isinstance(e, jax.core.ClosedJaxpr):
+        if isinstance(e, jex_core.ClosedJaxpr):
             yield e.jaxpr
-        elif isinstance(e, jax.core.Jaxpr):
+        elif isinstance(e, jex_core.Jaxpr):
             yield e
 
 
@@ -57,13 +66,14 @@ def _subjaxprs_of(v):
 
 class TestElementwiseEpilogues:
     @pytest.mark.parametrize("act", epi.EPILOGUES)
-    @pytest.mark.parametrize("lookup", epi.LOOKUPS)
+    @pytest.mark.parametrize("blocks", [(epi.DEFAULT_BLOCK_ROWS,
+                                         epi.DEFAULT_BLOCK_COLS), (8, 128)])
     @pytest.mark.parametrize("shape", [(8, 128), (3, 100), (257, 129),
                                        (4, 7, 64)])
-    def test_kernel_matches_oracle(self, act, lookup, shape):
+    def test_kernel_matches_oracle(self, act, blocks, shape):
         x = rand(shape, seed=sum(shape))
         table = epi.table_for(act, 4.0, 32)
-        y = ops.act(x, act, lookup=lookup)
+        y = ops.act(x, act, block_rows=blocks[0], block_cols=blocks[1])
         yr = ref.act_ref(x, act, table)
         assert y.shape == x.shape and y.dtype == x.dtype
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
@@ -122,13 +132,19 @@ class TestFusedGluEpilogues:
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                    rtol=1e-4, atol=1e-5)
 
-    @pytest.mark.parametrize("lookup", epi.LOOKUPS)
-    def test_lookup_strategies_agree(self, lookup):
+    @pytest.mark.parametrize("act", ["silu", "softplus"])
+    def test_lookup_strategies_agree(self, act):
+        # the kernel's in-kernel select chain against the XLA ``take``
+        # recompute of its custom-VJP backward (depth 32 and, for the
+        # softplus residual, depth 64)
         x = rand((16, 256), scale=1.0, seed=11)
         wg = rand((256, 128), scale=0.05, seed=12)
         wu = rand((256, 128), scale=0.05, seed=13)
-        y = ops.fused_glu(x, wg, wu, lookup=lookup)
-        yr = ops.fused_glu(x, wg, wu, lookup="onehot")
+        spec = epi._spec_for_epilogue(act, "cr_spline", 4.0, 32)
+        y = ops.fused_glu(x, wg, wu, act=act)
+        yr = ops._fused_glu_ref_math(
+            (spec, act), x, wg, wu,
+            jnp.asarray(epi.params_for(act, spec), jnp.float32))
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr), atol=1e-6)
 
     def test_grads_flow_through_fused(self):

@@ -35,11 +35,12 @@ class TestCrAct:
             np.asarray(y, np.float32), np.asarray(yr, np.float32),
             rtol=1e-5, atol=1e-6)
 
-    @pytest.mark.parametrize("lookup", ["onehot", "take"])
+    # the in-kernel select chain at every table depth, on two block grids
+    @pytest.mark.parametrize("blocks", [(8, 128), (32, 256)])
     @pytest.mark.parametrize("table", [TAB8, TAB32, TAB64])
-    def test_lookup_strategies_and_depths(self, lookup, table):
+    def test_lookup_strategies_and_depths(self, blocks, table):
         x = rand((32, 256), jnp.float32, seed=1)
-        y = ops.cr_act(x, table, lookup=lookup)
+        y = ops.cr_act(x, table, block_rows=blocks[0], block_cols=blocks[1])
         yr = cr_act_ref(x, table)
         np.testing.assert_allclose(np.asarray(y), np.asarray(yr),
                                    rtol=1e-5, atol=1e-6)

@@ -290,6 +290,20 @@ class TestServeBatchWrapper:
         tp, _ = _serve_batch_python(cfg, params, prompts, 8)
         np.testing.assert_array_equal(np.asarray(te), np.asarray(tp))
 
+    def test_ragged_prompt_list_matches_lockstep_per_length(self):
+        """A list of prompts of their own lengths serves in one call; each
+        row equals the lockstep reference run on its prompt alone."""
+        from repro.launch.serve import _serve_batch_python, serve_batch
+        cfg, params = setup("qwen3-0.6b")
+        rng = np.random.RandomState(4)
+        prompts = [rng.randint(0, cfg.vocab_size, n).astype(np.int32)
+                   for n in (5, 12, 9)]
+        te, stats = serve_batch(cfg, params, prompts, 6)
+        assert te.shape == (3, 6) and stats.prompt_len == 12
+        for row, p in zip(np.asarray(te), prompts):
+            tp, _ = _serve_batch_python(cfg, params, jnp.asarray(p)[None], 6)
+            np.testing.assert_array_equal(row, np.asarray(tp)[0])
+
     def test_serve_batch_has_no_backend_switch(self):
         """The python backend is retired from the serving path: serve_batch
         accepts no backend selector (the lockstep loop survives only as

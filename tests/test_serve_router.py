@@ -531,30 +531,3 @@ class TestRoutedTokenIdentity:
         assert total.decode_steps > 0
         per_rep = [r.stats() for r in router.replicas.values()]
         assert total.decode_tokens == sum(s.decode_tokens for s in per_rep)
-
-
-@pytest.mark.slow
-class TestProcessReplica:
-    def test_subprocess_matches_in_process(self):
-        """One request through a spawned worker replica equals the
-        in-process engine token-for-token (worker materializes the same
-        seed-0 params itself)."""
-        from repro.serve import ProcessReplica, ReplicaSpec
-        cfg, params = setup()
-        prompts = make_prompts(cfg, [9, 14], seed=5)
-        gen = 4
-        ecfg = dict(slots=2, max_prompt_len=32, max_len=40, chunk=4)
-        single = ServeEngine(cfg, params, EngineConfig(**ecfg))
-        for p in prompts:
-            single.submit(p, max_new=gen)
-        base = {c.uid: c.tokens for c in single.run()}
-        router = Router(
-            lambda rid: ProcessReplica(ReplicaSpec(engine=ecfg)),
-            RouterConfig(replicas=1))
-        try:
-            for p in prompts:
-                router.submit(p, max_new=gen)
-            done = router.run()
-            assert {c.uid: c.tokens for c in done} == base
-        finally:
-            router.close()
