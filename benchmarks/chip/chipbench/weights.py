@@ -1,0 +1,76 @@
+"""Random weights made on the device from the seed, in one jitted call.
+
+The fill runs over the shapes of the model's parameter tree (as
+``jax.eval_shape`` of its init gives them) and draws every leaf at the
+scale the init uses, by the leaf's name. The same seed gives the same
+values to the program (in its serving dtype) and to the reference (the
+same values, widened to float32), so the reference needs nothing the
+program made.
+"""
+from __future__ import annotations
+
+import math
+import zlib
+
+import jax
+import jax.numpy as jnp
+
+# leaf name -> how it is drawn. ("ones",) / ("zeros",) / ("normal", s)
+# with s a number, or ("fan_in", n): 1/sqrt of the product of the n
+# dimensions after the leading layer axis (stacked block weights), or of
+# the first n dimensions for a leaf outside the stack.
+RULES = {
+    "scale": ("ones",), "q_norm": ("ones",), "k_norm": ("ones",),
+    "bq": ("zeros",), "bk": ("zeros",), "bv": ("zeros",),
+    "embed": ("normal", 0.02),
+    "lm_head": ("fan_in", 1),
+    "wq": ("fan_in", 1), "wk": ("fan_in", 1), "wv": ("fan_in", 1),
+    "wo": ("fan_in", 2),
+    "w_gate": ("fan_in", 1), "w_up": ("fan_in", 1), "w_down": ("fan_in", 1),
+}
+
+
+def _names(path) -> list[str]:
+    return [str(getattr(k, "key", getattr(k, "name", k))) for k in path]
+
+
+def root_key(seed: int):
+    """A key for any whole-number seed, also one wider than 32 bits."""
+    seed = int(seed)
+    key = jax.random.key(seed & 0xFFFFFFFF)
+    return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
+
+
+def rule_of(names: list[str]):
+    rule = RULES.get(names[-1])
+    if rule is None:
+        raise KeyError(f"no weight rule for leaf {'/'.join(names)}")
+    return rule
+
+
+def _leaf(key, names, shape, dtype):
+    rule = rule_of(names)
+    if rule[0] == "ones":
+        return jnp.ones(shape, dtype)
+    if rule[0] == "zeros":
+        return jnp.zeros(shape, dtype)
+    if rule[0] == "normal":
+        scale = rule[1]
+    else:
+        lead = 1 if "blocks" in names else 0
+        scale = 1.0 / math.sqrt(math.prod(shape[lead:lead + rule[1]]))
+    k = jax.random.fold_in(key, zlib.crc32("/".join(names).encode()))
+    return (jax.random.normal(k, shape, jnp.float32) * scale).astype(dtype)
+
+
+def fill(shapes, seed: int, dtype=jnp.bfloat16):
+    """Arrays for every leaf of ``shapes`` (a tree of ShapeDtypeStruct),
+    made in one jitted call on the default device."""
+    leaves, treedef = jax.tree_util.tree_flatten_with_path(shapes)
+    specs = [(_names(p), tuple(s.shape)) for p, s in leaves]
+
+    @jax.jit
+    def make(key):
+        return [_leaf(key, n, shp, dtype) for n, shp in specs]
+
+    return jax.tree_util.tree_unflatten(treedef, make(root_key(seed)))

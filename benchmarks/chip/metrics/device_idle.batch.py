@@ -1,0 +1,6 @@
+"""Device: 1 - union of device-op intervals over the traced window (%)."""
+from chipbench import readers
+
+
+def read(run):
+    return readers.device_idle(run)
