@@ -21,6 +21,7 @@ from __future__ import annotations
 
 from typing import Callable, NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -91,21 +92,24 @@ def interpolate(table: SplineTable, x, odd: bool = True):
     """Float CR interpolation of the tabled function at x (pure jnp oracle).
 
     ``odd=True`` applies the paper's odd-symmetry trick: evaluate on |x|
-    and restore the sign. Out-of-range |x| >= x_max saturates.
+    and restore the sign. Out-of-range |x| >= x_max saturates. The ops
+    carry the ``cr_spline`` scope, by which a device trace finds them.
     """
-    x = jnp.asarray(x)
-    ax = jnp.abs(x) if odd else x
-    u = ax / table.period
-    k = jnp.clip(jnp.floor(u), 0, table.depth - 1).astype(jnp.int32)
-    t = u - k.astype(u.dtype)                      # in [0,1)
-    w = basis_weights(t)                           # [..., 4]
-    windows = jnp.asarray(table.windows, dtype=x.dtype)  # [depth, 4]
-    p = windows[k]                                 # [..., 4]
-    y = jnp.sum(p * w, axis=-1)
-    y = jnp.where(ax >= table.x_max, jnp.asarray(table.saturation, y.dtype), y)
-    if odd:
-        y = jnp.where(x < 0, -y, y)
-    return y.astype(x.dtype)
+    with jax.named_scope("cr_spline"):
+        x = jnp.asarray(x)
+        ax = jnp.abs(x) if odd else x
+        u = ax / table.period
+        k = jnp.clip(jnp.floor(u), 0, table.depth - 1).astype(jnp.int32)
+        t = u - k.astype(u.dtype)                      # in [0,1)
+        w = basis_weights(t)                           # [..., 4]
+        windows = jnp.asarray(table.windows, dtype=x.dtype)  # [depth, 4]
+        p = windows[k]                                 # [..., 4]
+        y = jnp.sum(p * w, axis=-1)
+        y = jnp.where(ax >= table.x_max,
+                      jnp.asarray(table.saturation, y.dtype), y)
+        if odd:
+            y = jnp.where(x < 0, -y, y)
+        return y.astype(x.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -197,7 +197,12 @@ def _on_platform(call, *args):
     is lowered for, not by the process's default backend: compiled by
     Mosaic for a TPU, interpreted for the CPU, and refused (no branch)
     anywhere else. A TPU compile rehearsed from a CPU-only process
-    therefore holds the real kernel."""
+    therefore holds the real kernel.
+
+    The builders name their kernel by a ``named_scope`` around this call,
+    which reaches only the ops' metadata: the HLO instruction keeps the
+    branch's name (``branch_0_fun.N``), where ``pallas_call(name=...)``
+    would rename it."""
     return jax.lax.platform_dependent(
         *args, cpu=functools.partial(call, interpret=True),
         tpu=functools.partial(call, interpret=False))
@@ -251,7 +256,8 @@ def elementwise_2d(x, params, *, spec: TableSpec, act: str = "tanh",
             interpret=interpret,
         )(x, params)
 
-    return _on_platform(call, x, params)
+    with jax.named_scope("elementwise_2d"):
+        return _on_platform(call, x, params)
 
 
 # ---------------------------------------------------------------------------
@@ -319,4 +325,5 @@ def glu_2d(x, w_gate, w_up, params, *, spec: TableSpec, act: str = "silu",
             interpret=interpret,
         )(x, w_gate, w_up, params)
 
-    return _on_platform(call, x, w_gate, w_up, params)
+    with jax.named_scope("glu_2d"):
+        return _on_platform(call, x, w_gate, w_up, params)

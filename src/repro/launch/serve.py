@@ -50,6 +50,8 @@ class ServeStats:
                             # EngineStats' accounting, so the engine and
                             # the lockstep reference agree exactly)
     planes: int = 1         # codebook count K of the served arch
+    step_s: float = 0.0     # engine step() calls that did work
+    wait_s: float = 0.0     # of which the host blocked on the device
 
     @property
     def prefill_tokens_per_s(self):
@@ -190,7 +192,8 @@ def serve_batch(cfg, params, prompts, gen_tokens: int, *,
     st = engine.stats
     return tokens, ServeStats(st.prefill_s, st.decode_s, B, S, gen_tokens,
                               decode_steps=st.decode_steps,
-                              decode_tokens=st.decode_tokens, planes=K)
+                              decode_tokens=st.decode_tokens, planes=K,
+                              step_s=st.step_s, wait_s=st.wait_s)
 
 
 def serve_routed(cfg, params, prompts, gen_tokens: int, *,
@@ -236,7 +239,8 @@ def serve_routed(cfg, params, prompts, gen_tokens: int, *,
     st = router.engine_totals()
     stats = ServeStats(st.prefill_s, st.decode_s, B, S, gen_tokens,
                        decode_steps=st.decode_steps,
-                       decode_tokens=st.decode_tokens, planes=K)
+                       decode_tokens=st.decode_tokens, planes=K,
+                       step_s=st.step_s, wait_s=st.wait_s)
     return jnp.asarray(rows), stats, router
 
 
@@ -383,7 +387,8 @@ def main(argv=None):
           f"({stats.prefill_s*1e3:.0f} ms), decode "
           f"{stats.decode_tokens_per_s:,.0f} tok/s "
           f"({stats.decode_s*1e3:.0f} ms for {stats.decode_steps} steps, "
-          f"{args.batch} seqs)")
+          f"{args.batch} seqs); device wait {stats.wait_s*1e3:.0f} ms, "
+          f"host {(stats.step_s - stats.wait_s)*1e3:.0f} ms")
     print("[serve] sample output tokens:", np.asarray(tokens)[0, :16].tolist())
     if args.json:
         doc = dataclasses.asdict(stats)

@@ -80,7 +80,9 @@ the slot dim is host-addressed (see `parallel/partition.py::serve_rules`).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 import time
 from typing import Optional
 
@@ -96,6 +98,7 @@ from repro.parallel import partition as part
 from .paging import PagePool, SlotPages
 from .scheduler import (Completion, Request, SlotRun, TokenBudgetScheduler,
                         bucket_len)
+from .spans import SpanRecorder
 
 
 def sample_tokens(key, logits, temperature):
@@ -224,7 +227,8 @@ def make_prefix_prefill_sample(cfg: ModelConfig, n_pre: int, page_size: int,
     engine = steps_mod.make_engine(cfg)
     prefix_len = n_pre * page_size
 
-    def prefill_sample(params, pool_kv, pages, batch, uids, key, temperature):
+    def prefix_prefill_sample(params, pool_kv, pages, batch, uids, key,
+                              temperature):
         prefix = {}
         for name in ("k", "v"):
             sel = pool_kv[name][:, pages]             # [L, n_pre, ps, KV, hd]
@@ -237,7 +241,7 @@ def make_prefix_prefill_sample(cfg: ModelConfig, n_pre: int, page_size: int,
         return sample_tokens_indexed(key, uids, idx0, logits,
                                      temperature), cache
 
-    return prefill_sample
+    return prefix_prefill_sample
 
 
 def make_decode_chunk(cfg: ModelConfig, n_steps: int, paged: bool = False):
@@ -259,7 +263,7 @@ def make_decode_chunk(cfg: ModelConfig, n_steps: int, paged: bool = False):
     engine = steps_mod.make_engine(cfg)
     K = cfg.n_codebooks
 
-    def chunk(params, cache, state):
+    def decode_chunk(params, cache, state):
         budget, temp, eos = state["budget"], state["temp"], state["eos"]
         base, uid = state["key"], state["uid"]
 
@@ -286,7 +290,7 @@ def make_decode_chunk(cfg: ModelConfig, n_steps: int, paged: bool = False):
         new_state = dict(state, tok=tok, emitted=emitted, active=active)
         return cache, new_state, toks
 
-    return chunk
+    return decode_chunk
 
 
 def make_chunk_prefill(cfg: ModelConfig, page_size: int):
@@ -310,8 +314,8 @@ def make_chunk_prefill(cfg: ModelConfig, page_size: int):
     step = steps_mod.make_prefill_chunk_step(cfg, page_size)
     K = cfg.n_codebooks
 
-    def chunk(params, cache, state, batch, slot, pos, clen, first, final,
-              uid, key, temp, budget, eos):
+    def prefill_chunk(params, cache, state, batch, slot, pos, clen, first,
+                      final, uid, key, temp, budget, eos):
         W = cache["k_pos"].shape[1]
         j = jnp.arange(W, dtype=jnp.int32)
         # first chunk: forget the slot's previous occupant. A prefix hit
@@ -347,7 +351,7 @@ def make_chunk_prefill(cfg: ModelConfig, page_size: int):
         arm("eos", eos)
         return new_cache, new_state, tok0
 
-    return chunk
+    return prefill_chunk
 
 
 @dataclasses.dataclass(frozen=True)
@@ -442,22 +446,26 @@ class EngineStats:
     a multi-codebook engine (K > 1) counts K per sequence position —
     what the embedding actually summed and the K heads actually emitted
     — so tok/s rates are comparable across K=1 and K>1 workloads (one
-    musicgen position is K plane tokens, not one)."""
+    musicgen position is K plane tokens, not one).
+
+    The timers are sums of the engine's spans (``ServeEngine.spans``):
+    ``decode_s``, ``prefill_s`` and ``insert_s`` each add up the
+    ``engine.dispatch`` and ``engine.wait`` spans of their kind,
+    ``wait_s`` every ``engine.wait``, and ``step_s`` the ``engine.step``
+    spans of the steps that did work. Under chunked prefill a chunk is
+    never synced on its own: ``prefill_s`` is the chunks' enqueue time
+    alone, their compute runs while the host waits for the next decode
+    chunk, so ``decode_s`` holds the previous step's prefill compute, and
+    a final chunk's first-token wait counts in ``wait_s`` only."""
     prefill_s: float = 0.0
     prefill_tokens: int = 0        # real prompt tokens prefilled
     prefill_padded_tokens: int = 0  # incl. bucket padding
     prefill_batches: int = 0       # admission dispatches
     prefill_requests: int = 0      # requests admitted across dispatches
-    insert_s: float = 0.0          # slot-insert dispatch time (the other
-                                   # half of admission: untimed before,
-                                   # so prefill_tokens_per_s overstated
-                                   # admission throughput)
+    insert_s: float = 0.0          # slot-insert dispatch + sync (the
+                                   # other half of one-shot admission)
     prefill_chunks: int = 0        # chunked admission: prefill chunk
-                                   # dispatches (non-final chunks are
-                                   # never synced, so chunked prefill_s
-                                   # counts dispatch time only — their
-                                   # compute overlaps the next decode
-                                   # sync and lands in decode_s)
+                                   # dispatches
     decode_s: float = 0.0
     decode_chunks: int = 0
     decode_steps: int = 0          # sum of per-chunk in-jit steps
@@ -466,6 +474,8 @@ class EngineStats:
     pages_peak: int = 0            # paged only: high-water mark of the above
     prefix_hit_tokens: int = 0     # prompt tokens admitted straight from
                                    # cached prefix pages (never prefilled)
+    step_s: float = 0.0            # step() calls that did work
+    wait_s: float = 0.0            # host blocked on the device
     # live-occupancy gauges: filled by ServeEngine.snapshot() (a
     # point-in-time copy), NOT maintained on the engine's own cumulative
     # `stats` object — they describe "now", not "since boot". The
@@ -538,6 +548,11 @@ _STAT_GAUGES = frozenset({
     "slots_in_use", "queue_depth", "pages_free",
     "pages_in_use", "pages_peak",
 })
+
+# the EngineStats timer fed by the dispatch and wait spans of each kind;
+# the chunked schedule's first-token waits ("first_token") feed wait_s only
+_KIND_TIMERS = {"decode": "decode_s", "prefill": "prefill_s",
+                "insert": "insert_s"}
 
 
 class StatsWindow:
@@ -710,6 +725,9 @@ class ServeEngine:
         self.stats = EngineStats()
         self.completions: list[Completion] = []
         self._uid = 0
+        self._rec = SpanRecorder()
+        self.spans = self._rec.spans   # bounded: the newest SPAN_CAP spans
+        self._n_step = 0
 
     def _decode_at(self, n_steps: int):
         """The jitted decode chunk running ``n_steps`` in-jit steps,
@@ -781,11 +799,34 @@ class ServeEngine:
         manager only runs at trace time, cached calls skip it."""
         mesh, rules = self.mesh, self.rules
 
+        @functools.wraps(fn)            # the program keeps fn's name
         def traced(*args):
             with part.axis_rules(mesh, rules):
                 return fn(*args)
 
         return traced
+
+    # -- spans and the timers they feed ------------------------------------
+
+    @contextlib.contextmanager
+    def _device(self, name: str, kind: str, **attrs):
+        """An ``engine.dispatch`` or ``engine.wait`` span of one kind of
+        work. Its duration feeds that kind's timer (decode, prefill,
+        insert) and, for a wait, ``wait_s``."""
+        with self._rec.span(name, kind=kind, **attrs) as sp:
+            yield sp
+        dt = sp.t1 - sp.t0
+        timer = _KIND_TIMERS.get(kind)
+        if timer is not None:
+            setattr(self.stats, timer, getattr(self.stats, timer) + dt)
+        if name == "engine.wait":
+            self.stats.wait_s += dt
+
+    def _dispatch(self, kind: str, **attrs):
+        return self._device("engine.dispatch", kind, **attrs)
+
+    def _wait(self, kind: str, **attrs):
+        return self._device("engine.wait", kind, **attrs)
 
     # -- request intake ----------------------------------------------------
 
@@ -987,20 +1028,22 @@ class ServeEngine:
         uids = jnp.asarray([r.uid for r in reqs], jnp.int32)
         temps = jnp.asarray([r.temperature for r in reqs], jnp.float32)
 
-        t0 = time.perf_counter()
-        if n_pre:
-            pool_kv = {"k": self.cache["layers"]["k"],
-                       "v": self.cache["layers"]["v"]}
-            pages = jnp.asarray(plans[0].pages[:n_pre], jnp.int32)
-            tok0, small_cache = self._prefix_prefill_at(n_pre, bucket)(
-                self.params, pool_kv, pages, batch, uids, self._base_key,
-                temps)
-        else:
-            tok0, small_cache = self._prefill(self.params, batch, uids,
-                                              self._base_key, temps)
-        tok0 = np.asarray(tok0)                        # [N(, K)] ints; syncs
-        now = time.perf_counter()
-        self.stats.prefill_s += now - t0
+        program = "prefix_prefill_sample" if n_pre else "prefill_sample"
+        with self._dispatch("prefill", program=program, requests=N,
+                            tokens=sum(lens), bucket=bucket):
+            if n_pre:
+                pool_kv = {"k": self.cache["layers"]["k"],
+                           "v": self.cache["layers"]["v"]}
+                pages = jnp.asarray(plans[0].pages[:n_pre], jnp.int32)
+                tok0, small_cache = self._prefix_prefill_at(n_pre, bucket)(
+                    self.params, pool_kv, pages, batch, uids,
+                    self._base_key, temps)
+            else:
+                tok0, small_cache = self._prefill(self.params, batch, uids,
+                                                  self._base_key, temps)
+        with self._wait("prefill") as w:
+            tok0 = np.asarray(tok0)                    # [N(, K)] ints
+        now = w.t1
         # token stats count PLANE tokens (positions x K): what the model
         # actually embedded/emitted, so K=1 and K>1 rates are comparable
         self.stats.prefill_tokens += sum(lens) * self.K
@@ -1059,13 +1102,13 @@ class ServeEngine:
                 own = sp.pages[n_pre:]
                 write_rows[i, :min(len(own), n_w)] = own[:n_w]
             insert_args += [jnp.asarray(tbl_rows), jnp.asarray(write_rows)]
-        t0 = time.perf_counter()
-        self.cache, self.state = self._insert(*insert_args)
+        with self._dispatch("insert", program="insert", requests=N):
+            self.cache, self.state = self._insert(*insert_args)
         # the insert is the other half of admission: sync (any output of
-        # the one dispatch) so its cost lands in the stats instead of
+        # the one dispatch) so its cost lands in insert_s instead of
         # being silently attributed to the next decode chunk
-        jax.block_until_ready(self.state["tok"])
-        self.stats.insert_s += time.perf_counter() - t0
+        with self._wait("insert"):
+            jax.block_until_ready(self.state["tok"])
         if self.paged:
             self._tbl[slots[:N]] = tbl_rows    # mirror == device now
             if self.prefix_enabled:
@@ -1177,10 +1220,29 @@ class ServeEngine:
     def step(self) -> bool:
         """One engine iteration. Chunked engines pack a token budget
         (decode chunk + one prefill chunk per mid-prompt slot); legacy
-        engines run admit-then-decode. Returns False when idle."""
-        if self.chunked:
-            return self._step_chunked()
-        self._admit_ready()
+        engines run admit-then-decode. Returns False when idle.
+
+        An iteration with anything queued or bound is one
+        ``engine.step`` span (attrs: ``step`` index, decode slots
+        ``n_slots``, in-jit decode steps ``n_steps``, prefill chunks
+        ``n_chunks``), holding ``engine.admit``, ``engine.pages``, one
+        ``engine.dispatch`` per program dispatched, one ``engine.wait``
+        per host block on the device, and ``engine.harvest``."""
+        if not self.sched.pending:
+            return False
+        with self._rec.span("engine.step", step=self._n_step, n_slots=0,
+                            n_steps=0, n_chunks=0) as sp:
+            did = (self._step_chunked(sp.attrs) if self.chunked
+                   else self._step_phased(sp.attrs))
+        self._n_step += 1
+        if did:
+            self.stats.step_s += sp.t1 - sp.t0
+        return did
+
+    def _step_phased(self, attrs: dict) -> bool:
+        """Admit, then one decode chunk over every bound slot."""
+        with self._rec.span("engine.admit"):
+            self._admit_ready()
         active = self.sched.active_slots()
         if not active:
             return False
@@ -1201,19 +1263,21 @@ class ServeEngine:
                 for run in (self.sched.slots[b] for b in active))
             n_steps = max(1, min(n_steps, need))
 
-        decode = self._decode_at(n_steps)
+        attrs.update(n_slots=len(active), n_steps=n_steps)
         if self.paged:
-            self._grow_pages(active, n_steps)
-            self._push_tbl()
-        t0 = time.perf_counter()
-        self.cache, self.state, toks = decode(
-            self.params, self.cache, self.state)
-        toks = np.asarray(toks)                            # [T, B]; syncs
-        now = time.perf_counter()
-        self.stats.decode_s += now - t0
+            with self._rec.span("engine.pages"):
+                self._grow_pages(active, n_steps)
+                self._push_tbl()
+        with self._dispatch("decode", program="decode_chunk",
+                            n_steps=n_steps, n_slots=len(active)):
+            self.cache, self.state, toks = self._decode_at(n_steps)(
+                self.params, self.cache, self.state)
+        with self._wait("decode") as w:
+            toks = np.asarray(toks)                        # [T, B]
         self.stats.decode_chunks += 1
         self.stats.decode_steps += toks.shape[0]
-        self._harvest(active, toks, now)
+        with self._rec.span("engine.harvest"):
+            self._harvest(active, toks, w.t1)
         return True
 
     def _harvest(self, active: list, toks, now: float) -> None:
@@ -1243,7 +1307,7 @@ class ServeEngine:
                         token_times=run.token_times)
                     break
 
-    def _step_chunked(self) -> bool:
+    def _step_chunked(self, attrs: dict) -> bool:
         """One token-budget iteration: plan decode steps + prefill
         chunks, dispatch the decode chunk FIRST (its sync then never
         waits on chunk compute — chunk dispatches overlap the decode
@@ -1252,7 +1316,8 @@ class ServeEngine:
         Final-chunk slots sample their first token on device inside the
         chunk dispatch and flip active there, so they join the NEXT
         iteration's decode chunk with zero extra dispatches."""
-        self._admit_ready()
+        with self._rec.span("engine.admit"):
+            self._admit_ready()
         active = self.sched.active_slots()
         if not active:
             return False
@@ -1275,15 +1340,18 @@ class ServeEngine:
                 (b, len(self.sched.slots[b].request.tokens)
                  - self._slot_pages[b].prefill_pos) for b in pf])
 
-        if dec:
-            self._grow_pages(dec, plan.decode_steps)
-        self._push_tbl()        # one upload covers decode AND chunks
+        attrs.update(n_slots=len(dec), n_steps=plan.decode_steps,
+                     n_chunks=len(plan.chunks))
+        with self._rec.span("engine.pages"):
+            if dec:
+                self._grow_pages(dec, plan.decode_steps)
+            self._push_tbl()    # one upload covers decode AND chunks
         toks = None
         if dec:
-            decode = self._decode_at(plan.decode_steps)
-            t0 = time.perf_counter()
-            self.cache, self.state, toks = decode(
-                self.params, self.cache, self.state)
+            with self._dispatch("decode", program="decode_chunk",
+                                n_steps=plan.decode_steps, n_slots=len(dec)):
+                self.cache, self.state, toks = self._decode_at(
+                    plan.decode_steps)(self.params, self.cache, self.state)
 
         finals = []
         for b, c in plan.chunks:
@@ -1297,18 +1365,19 @@ class ServeEngine:
             padded = np.zeros(shape, np.int32)
             padded[0, :c] = np.asarray(req.tokens[pos:pos + c], np.int32)
             gen = min(req.max_new, self.ecfg.max_len - len(req.tokens))
-            tc = time.perf_counter()
-            self.cache, self.state, tok0 = self._chunk_at(sbucket)(
-                self.params, self.cache, self.state,
-                {"tokens": jnp.asarray(padded)},
-                jnp.int32(b), jnp.int32(pos), jnp.int32(c),
-                jnp.asarray(sp.first_chunk), jnp.asarray(final),
-                jnp.int32(req.uid), self._base_key,
-                jnp.full((1,), req.temperature, jnp.float32),
-                jnp.int32(gen), jnp.int32(req.eos_id))
-            # dispatch-enqueue time only: chunks are never synced here,
-            # their compute overlaps the next decode sync (decode_s)
-            self.stats.prefill_s += time.perf_counter() - tc
+            # enqueue time only: chunks are never synced here, their
+            # compute overlaps the next decode wait
+            with self._dispatch("prefill", program="prefill_chunk",
+                                uid=req.uid, slot=b, pos=pos, tokens=c,
+                                bucket=sbucket):
+                self.cache, self.state, tok0 = self._chunk_at(sbucket)(
+                    self.params, self.cache, self.state,
+                    {"tokens": jnp.asarray(padded)},
+                    jnp.int32(b), jnp.int32(pos), jnp.int32(c),
+                    jnp.asarray(sp.first_chunk), jnp.asarray(final),
+                    jnp.int32(req.uid), self._base_key,
+                    jnp.full((1,), req.temperature, jnp.float32),
+                    jnp.int32(gen), jnp.int32(req.eos_id))
             self.stats.prefill_chunks += 1
             self.stats.prefill_tokens += c * self.K
             self.stats.prefill_padded_tokens += sbucket * self.K
@@ -1319,37 +1388,42 @@ class ServeEngine:
                 finals.append((b, tok0))
 
         if toks is not None:
-            toks = np.asarray(toks)                        # [T, B]; syncs
-            now = time.perf_counter()
-            self.stats.decode_s += now - t0
+            with self._wait("decode") as w:
+                toks = np.asarray(toks)                    # [T, B]
             self.stats.decode_chunks += 1
             self.stats.decode_steps += toks.shape[0]
-            self._harvest(dec, toks, now)
+            with self._rec.span("engine.harvest"):
+                self._harvest(dec, toks, w.t1)
 
-        ps = self.ecfg.page_size
         for b, tok0 in finals:
-            raw = np.asarray(tok0)
-            t = self._head(raw)
-            now = time.perf_counter()
-            run = self.sched.slots[b]
-            req = run.request
-            sp = self._slot_pages[b]
-            if self.prefix_enabled:
-                n_full = len(req.tokens) // ps
-                self._pool.register(req.tokens[:n_full * ps],
-                                    sp.pages[:n_full])
-            run.tokens.append(self._as_token(raw))
-            run.token_times.append(now)
-            gen = min(req.max_new, self.ecfg.max_len - len(req.tokens))
-            if t == req.eos_id or gen <= 1:
-                self.sched.evict(b)
-                self._free_slot(b)
-                self._complete(
-                    req, run.tokens,
-                    "eos" if t == req.eos_id else "length",
-                    admitted_at=run.admitted_at,
-                    token_times=run.token_times)
+            uid = self.sched.slots[b].request.uid
+            with self._wait("first_token", uid=uid) as w:
+                raw = np.asarray(tok0)
+            with self._rec.span("engine.harvest", uid=uid):
+                self._harvest_first(b, raw, w.t1)
         return True
+
+    def _harvest_first(self, b: int, raw, now: float) -> None:
+        """Record a final chunk's synced first token: register the
+        prompt's full pages as a prefix chain, then complete the request
+        if that token ends it (EOS, or a budget of one)."""
+        run = self.sched.slots[b]
+        req = run.request
+        sp = self._slot_pages[b]
+        if self.prefix_enabled:
+            ps = self.ecfg.page_size
+            n_full = len(req.tokens) // ps
+            self._pool.register(req.tokens[:n_full * ps], sp.pages[:n_full])
+        t = self._head(raw)
+        run.tokens.append(self._as_token(raw))
+        run.token_times.append(now)
+        gen = min(req.max_new, self.ecfg.max_len - len(req.tokens))
+        if t == req.eos_id or gen <= 1:
+            self.sched.evict(b)
+            self._free_slot(b)
+            self._complete(
+                req, run.tokens, "eos" if t == req.eos_id else "length",
+                admitted_at=run.admitted_at, token_times=run.token_times)
 
     def run(self) -> list[Completion]:
         """Serve until queue and slots drain. Completions in uid order."""

@@ -105,3 +105,53 @@ def test_tp_serving_token_identical_to_single_device():
         capture_output=True, text=True, timeout=1200)
     assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
     assert "TP-IDENTITY-OK" in proc.stdout
+
+
+NAMES_SCRIPT = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
+import jax
+import jax.numpy as jnp
+
+from repro.configs import registry
+from repro.launch.mesh import make_host_mesh
+from repro.models import model as M
+from repro.parallel import partition as part
+from repro.serve import EngineConfig, ServeEngine
+
+def module_name(lowered):
+    head = lowered.as_text().split("\n", 1)[0]
+    return head.split("@", 1)[1].split(" ", 1)[0]
+
+cfg = registry.get("qwen3-0.6b", smoke=True)
+params, _ = M.materialize_params(cfg, seed=0)
+mesh = make_host_mesh(1, 2)
+i32 = jnp.int32
+with part.axis_rules(mesh):
+    eng = ServeEngine(cfg, params, EngineConfig(
+        slots=2, max_prompt_len=16, max_len=24, page_size=4,
+        chunk_prefill=8), mesh=mesh)
+    dec = eng._decode_at(eng.ecfg.chunk).lower(eng.params, eng.cache,
+                                               eng.state)
+    pf = eng._chunk_at(8).lower(
+        eng.params, eng.cache, eng.state, {"tokens": jnp.zeros((1, 8), i32)},
+        i32(0), i32(0), i32(3), jnp.asarray(True), jnp.asarray(True),
+        i32(0), eng._base_key, jnp.zeros((1,), jnp.float32), i32(4), i32(-1))
+names = (module_name(dec), module_name(pf))
+assert names == ("jit_decode_chunk", "jit_prefill_chunk"), names
+print("TP-NAMES-OK")
+"""
+
+
+def test_tp_engine_programs_keep_their_names():
+    """Under a mesh every program is traced through the engine's rule
+    context; the wrapper keeps the program's name, so a TP engine's
+    modules read ``jit_decode_chunk`` / ``jit_prefill_chunk`` too."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO / "src") + os.pathsep + env.get(
+        "PYTHONPATH", "")
+    proc = subprocess.run(
+        [sys.executable, "-c", NAMES_SCRIPT], env=env, cwd=REPO,
+        capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
+    assert "TP-NAMES-OK" in proc.stdout
