@@ -647,19 +647,25 @@ def _attn_branch(p, xn, io: BlockIO, cfg: ModelConfig, engine):
     if io.mode == "decode":
         q, k_new, v_new = _qkv(p, xn, io.positions, cfg)
         if "page_tbl" in io.cache:
-            # paged contract: k/v are a shared page pool [P, ps, KV, hd];
-            # the row's ring is reassembled by gathering its page table.
-            # Writes from dead/unallocated rows land on the trash page
-            # (page 0) and are masked out via k_pos == -1.
+            # paged contract: k/v are the whole shared page pool
+            # [L, P, ps, KV, hd], carried through the layer scan, and
+            # `layer` is this layer's global index. The B new tokens are
+            # scattered in place at [layer, page, off]; the row's ring is
+            # then gathered through its page table, after the write, so
+            # a token attends to itself. Writes from dead/unallocated
+            # rows land on the trash page (page 0) and are masked out
+            # via k_pos == -1. The pool keeps its sharding on the carry.
             kc, vc = io.cache["k"], io.cache["v"]
+            l = io.cache["layer"]
             page, off = io.cache["page"], io.cache["off"]      # [B] int32
             tbl = io.cache["page_tbl"]                         # [B, n]
-            kc = kc.at[page, off].set(k_new[:, 0])
-            vc = vc.at[page, off].set(v_new[:, 0])
+            pool_axes = ("layer", "pages", "seq", "act_kv", None)
+            kc = lc(kc.at[l, page, off].set(k_new[:, 0]), *pool_axes)
+            vc = lc(vc.at[l, page, off].set(v_new[:, 0]), *pool_axes)
             B, n = tbl.shape
-            ring = (B, n * kc.shape[1]) + kc.shape[2:]         # [B, W, KV, hd]
-            ctx = decode_attention(q, kc[tbl].reshape(ring),
-                                   vc[tbl].reshape(ring),
+            ring = (B, n * kc.shape[2]) + kc.shape[3:]         # [B, W, KV, hd]
+            ctx = decode_attention(q, kc[l, tbl].reshape(ring),
+                                   vc[l, tbl].reshape(ring),
                                    io.q_pos, io.k_pos, cfg, engine)
         else:
             kc, vc = io.cache["k"], io.cache["v"]              # [B, W, KV, hd]

@@ -405,11 +405,18 @@ def run_stack_decode(params, x, batch, cfg: ModelConfig, engine, cache):
     [L, n_pages, page_size, KV, hd] instead of per-slot rows. The ring
     semantics are unchanged — logical ring slot `cur % W` lives at
     physical page `page_tbl[b, slot // page_size]`, offset
-    `slot % page_size` — so decode scatters one token through the table
-    and gathers the row's W keys back out, all with traced indices (no
-    host sync). Physical page 0 is the trash page: dead/unallocated
-    logical pages map there, their writes are discarded by construction
-    and their keys are masked (k_pos == -1)."""
+    `slot % page_size` — all with traced indices (no host sync). The
+    whole pool rides the layer scan's carry, and `xs` carries each
+    layer's params with its GLOBAL layer index (plus the per-slot
+    `conv`/`ssm` state of hybrid archs, still sliced per layer): each
+    layer scatters its B new tokens in place at [l, page, off] and
+    gathers the rows' rings from `pool[l, page_tbl]`, so under donation
+    the pool is updated in place and never sliced out or stacked back.
+    The index stays global across the segments of a `LayerEngines`
+    assignment, whose scans hand the pool on through the carry.
+    Physical page 0 is the trash page: dead/unallocated logical pages
+    map there, their writes are discarded by construction and their
+    keys are masked (k_pos == -1)."""
     B = x.shape[0]
     cur = cache["cur"]
     per_slot = jnp.ndim(cur) > 0
@@ -451,13 +458,17 @@ def run_stack_decode(params, x, batch, cfg: ModelConfig, engine, cache):
     else:
         k_pos_new = None
 
+    # the paged pool rides the carry; per-slot state is sliced per layer
+    layers = cache["layers"]
+    pool = {n: layers[n] for n in ("k", "v")} if tbl is not None else {}
+    per_layer = {n: a for n, a in layers.items() if n not in pool}
+
     def body_for(eng):
-        def scan_body(x, inp):
-            layer_params, layer_cache = inp
-            lcache = dict(layer_cache)
+        def scan_body(carry, inp):
+            (x, pool), (layer_params, l, layer_cache) = carry, inp
+            lcache = dict(layer_cache, **pool)
             if tbl is not None:
-                lcache["page"], lcache["off"], lcache["page_tbl"] = \
-                    page, off, tbl
+                lcache.update(layer=l, page=page, off=off, page_tbl=tbl)
             else:
                 lcache["slot"] = slot
             io = BlockIO(mode="decode", positions=positions, q_pos=cur_b,
@@ -465,12 +476,14 @@ def run_stack_decode(params, x, batch, cfg: ModelConfig, engine, cache):
             x, new_cache, _ = apply_block(layer_params, x, io, cfg, eng)
             # preserve untouched entries (e.g. nothing for pure attn)
             merged = {k: new_cache.get(k, v) for k, v in layer_cache.items()}
-            return x, merged
+            return (x, {n: new_cache[n] for n in pool}), merged
 
         return scan_body
 
-    x, new_layer_caches = _scan_layers(
-        engine, body_for, x, (params["blocks"], cache["layers"]))
+    (x, pool), per_layer = _scan_layers(
+        engine, body_for, (x, pool),
+        (params["blocks"], jnp.arange(cfg.n_layers), per_layer))
+    new_layer_caches = dict(per_layer, **pool)
     adv = 1 if wm is None else wm.astype(jnp.int32)
     new_cache = {"layers": new_layer_caches, "cur": cur + adv}
     if k_pos_new is not None:

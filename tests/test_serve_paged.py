@@ -12,10 +12,13 @@ backpressures instead of over-committing pages.
 """
 import dataclasses
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs import registry
+from repro.configs.common import act_layers_of
+from repro.launch import steps as steps_mod
 from repro.models import model as M
 from repro.serve import EngineConfig, ServeEngine
 from repro.serve.paging import PagePool
@@ -128,7 +131,7 @@ class TestPagePool:
         assert p.match([1, 2], limit=1) == a[:1]
 
 
-PAGED_ARCHS = ["qwen3-0.6b", "qwen2-vl-2b", "mixtral-8x22b"]
+PAGED_ARCHS = ["qwen3-0.6b", "qwen2-vl-2b", "mixtral-8x22b", "hymba-1.5b"]
 
 
 class TestPagedSlotIdentity:
@@ -139,7 +142,8 @@ class TestPagedSlotIdentity:
         ring write position wraps past page_size several times; the page
         size (5) deliberately divides neither the window nor the
         power-of-two buckets, so the ring is padded to whole pages and
-        the pad region must stay masked out."""
+        the pad region must stay masked out. hymba carries the pool
+        through the layer scan beside per-layer conv/ssm state."""
         cfg, params = setup(arch)
         prompts = make_prompts(cfg, [9, 17, 30, 12], seed=3)
         gen = 40 if cfg.sliding_window else 10
@@ -163,6 +167,68 @@ class TestPagedSlotIdentity:
             paged, _ = serve(cfg, params, prompts, 8, cache="paged",
                              page_size=ps)
             assert token_streams(paged) == token_streams(base), ps
+
+    def test_decode_writes_each_layer_at_its_global_index(self):
+        """One paged decode step under a two-segment LayerEngines
+        assignment: every layer l holds layer l's new K/V at
+        [l, page, off] for the live rows (the slot cache's step, from
+        the same rings, is the reference), and the row the write mask
+        freezes keeps its pages, cur and k_pos bit-identical."""
+        base = registry.get("qwen3-0.6b", smoke=True, n_layers=4)
+        cfg = act_layers_of(base, ("cr-d32", "cr-d32", "pwl-d16", "pwl-d16"))
+        params, _ = M.materialize_params(cfg, seed=0)
+        engine = steps_mod.make_engine(cfg)
+        assert [(s, t) for s, t, _ in engine.segments] == [(0, 2), (2, 4)]
+
+        B, ps, n, n_pages = 3, 4, 3, 12
+        cache = M.init_paged_cache(cfg, B, n_pages, ps, n * ps)
+        W = cache["k_pos"].shape[1]
+        rng = np.random.RandomState(11)
+        for name in ("k", "v"):
+            shape = cache["layers"][name].shape
+            cache["layers"][name] = jnp.asarray(
+                rng.normal(size=shape), cache["layers"][name].dtype)
+        tbl = np.arange(1, 1 + B * n, dtype=np.int32).reshape(B, n)
+        cur = np.array([3, 7, 5], np.int32)
+        j = np.arange(W)
+        cache["page_tbl"] = jnp.asarray(tbl)
+        cache["cur"] = jnp.asarray(cur)
+        cache["k_pos"] = jnp.asarray(
+            np.where(j[None, :] < cur[:, None], j[None, :], -1), jnp.int32)
+        live = np.array([True, False, True])
+        tokens = jnp.asarray(rng.randint(0, cfg.vocab_size, (B, 1)),
+                             jnp.int32)
+        x = M.embed_tokens(params, tokens, cfg)
+        _, paged = M.run_stack_decode(
+            params, x, {"tokens": tokens, "write_mask": jnp.asarray(live)},
+            cfg, engine, cache)
+
+        rings = {name: cache["layers"][name][:, tbl].reshape(
+            (cfg.n_layers, B, W) + cache["layers"][name].shape[3:])
+            for name in ("k", "v")}
+        slot_cache = {"layers": rings, "cur": cache["cur"],
+                      "k_pos": cache["k_pos"]}
+        _, slot = M.run_stack_decode(params, x, {"tokens": tokens}, cfg,
+                                     engine, slot_cache)
+
+        page, off = tbl[np.arange(B), (cur % W) // ps], (cur % W) % ps
+        for name in ("k", "v"):
+            new_pool = np.asarray(paged["layers"][name])
+            want = np.asarray(slot["layers"][name])
+            old_pool = np.asarray(cache["layers"][name])
+            for b in np.flatnonzero(live):
+                np.testing.assert_array_equal(
+                    new_pool[:, page[b], off[b]], want[:, b, cur[b] % W])
+                assert not np.array_equal(new_pool[:, page[b], off[b]],
+                                          old_pool[:, page[b], off[b]])
+            frozen = tbl[~live].ravel()
+            np.testing.assert_array_equal(new_pool[:, frozen],
+                                          old_pool[:, frozen])
+        np.testing.assert_array_equal(np.asarray(paged["cur"]),
+                                      cur + live.astype(np.int32))
+        np.testing.assert_array_equal(np.asarray(paged["k_pos"])[~live],
+                                      np.asarray(cache["k_pos"])[~live])
+        np.testing.assert_array_equal(np.asarray(paged["page_tbl"]), tbl)
 
     def test_ssm_arch_falls_back_to_slot(self):
         """Pure-SSM archs have no KV ring to page; cache='paged' must
