@@ -113,6 +113,9 @@ class Run:
     counters: dict                 # engine counters over the window
     window_compiles: int
     trace: TraceData | None = None
+    modules: list | None = None    # the traced window's program runs on
+                                   # the first chip [(name, start_ns,
+                                   # dur_ns)] ("XLA Modules")
 
     def due_in_window(self):
         return due_in(self.records, *self.due_window)
@@ -345,8 +348,9 @@ def counters(a, b) -> dict:
     return {k: getattr(b, k) - getattr(a, k) for k in keys}
 
 
-def read_trace(trace_dir, t0: float, t1: float) -> TraceData:
-    ops_by_chip, spans = tr.read_xplane(trace_dir)
+def read_trace(trace_dir, t0: float, t1: float):
+    """(TraceData of the first chip, that chip's program runs)."""
+    ops_by_chip, spans, modules = tr.read_xplane(trace_dir)
     if not ops_by_chip:
         raise RuntimeError("the trace holds no device plane")
     marks = {n: s for n, s, _ in spans}
@@ -354,8 +358,9 @@ def read_trace(trace_dir, t0: float, t1: float) -> TraceData:
     hi = marks.get("bench.window_end")
     if lo is None or hi is None:
         raise RuntimeError("the trace lacks the window's marks")
-    ops = next(iter(ops_by_chip.values()))
-    return TraceData(ops=ops, spans=spans, lo=lo, hi=hi, t0=t0, t1=t1)
+    chip = next(iter(ops_by_chip))
+    return (TraceData(ops=ops_by_chip[chip], spans=spans, lo=lo, hi=hi,
+                      t0=t0, t1=t1), modules[chip])
 
 
 def parse_args(argv):
@@ -434,7 +439,8 @@ def main(argv=None, *, t_process: float | None = None, root=files.ROOT,
               counters=counters(out["stats_a"], out["stats_b"]),
               window_compiles=watch.count(out["a"], out["b"]))
     if tmp is not None:
-        run.trace = read_trace(tmp.name, out["trace_t0"], out["trace_t1"])
+        run.trace, run.modules = read_trace(tmp.name, out["trace_t0"],
+                                            out["trace_t1"])
         tmp.cleanup()
     log(f"[window] {run.window[1] - run.window[0]:.3f}s tokens="
         f"{run.tokens_in_window} counters={run.counters} "

@@ -20,7 +20,15 @@ if str(REPO / "src") not in sys.path:
 
 def model_config(conf: dict):
     """The program's ModelConfig for a configuration file, checked
-    against every size the file states."""
+    against every size and mechanism the file states.
+
+    Keys of the file are read by the program attribute they name (the
+    map below). A mechanism's key that the file leaves out takes its
+    dense value (no experts, no window, no bias, no Mamba layers, a gated
+    FFN, one codebook), so a
+    program that has the mechanism fails under a file that does not
+    state it. ``program.fields`` ({ModelConfig attribute: value}) names
+    any further attribute to hold to a value, read by ``getattr``."""
     from repro.configs import registry
     from repro.configs.common import fused_of
     prog = conf["program"]
@@ -28,6 +36,8 @@ def model_config(conf: dict):
     if prog.get("fused"):
         cfg = fused_of(cfg)
     act = conf["activation"]
+    moe = cfg.n_experts > 0
+    mamba = cfg.use_mamba or cfg.parallel_mamba
     have = {
         "hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
         "num_hidden_layers": cfg.n_layers,
@@ -40,11 +50,38 @@ def model_config(conf: dict):
         "activation.depth": cfg.activation.depth,
         "activation.x_max": cfg.activation.x_max,
         "activation.kernel": bool(cfg.activation.use_kernel and cfg.fuse_mlp),
+        "num_local_experts": cfg.n_experts,
+        "num_experts_per_tok": cfg.top_k if moe else 0,
+        "shared_expert_intermediate_size":
+            cfg.d_ff if moe and cfg.shared_expert else 0,
+        "sliding_window": cfg.sliding_window,
+        "attention_bias": cfg.qkv_bias,
+        "mamba_d_state": cfg.ssm_state if mamba else 0,
+        "mamba_d_conv": cfg.conv_kernel if mamba else 0,
+        "mamba_expand x hidden_size": cfg.d_inner_ if mamba else 0,
+        "mamba_dt_rank": cfg.dt_rank_ if mamba else 0,
+        "gated_ffn": cfg.glu if cfg.has_ffn else True,
+        "num_codebooks": cfg.n_codebooks,
     }
     want = dict(conf, **{f"activation.{k}": v for k, v in act.items()})
+    # a file's experts: Mixtral's key, or the one Jamba and Qwen-MoE
+    # use; one expert routed top-1 is a dense FFN
+    experts = int(conf.get("num_local_experts", conf.get("num_experts", 0)))
+    want["num_local_experts"] = experts if experts > 1 else 0
+    want["num_experts_per_tok"] = (conf.get("num_experts_per_tok", 0)
+                                   if experts > 1 else 0)
+    want["mamba_expand x hidden_size"] = (conf.get("mamba_expand", 0)
+                                          * conf["hidden_size"])
+    dense = {"shared_expert_intermediate_size": 0, "sliding_window": None,
+             "attention_bias": False, "mamba_d_state": 0, "mamba_d_conv": 0,
+             "mamba_dt_rank": 0, "gated_ffn": True, "num_codebooks": 1}
+    for k, v in dense.items():
+        want.setdefault(k, v)
     bad = {k: (want.get(k), v) for k, v in have.items() if want.get(k) != v}
-    if not cfg.glu or cfg.n_experts or cfg.sliding_window or cfg.qkv_bias:
-        bad["architecture"] = "not a dense gated-FFN decoder"
+    for k, v in prog.get("fields", {}).items():
+        got = getattr(cfg, k, "<no such attribute>")
+        if got != v:
+            bad[f"program.fields.{k}"] = (v, got)
     if bad:
         raise ValueError(f"program config {cfg.name} differs from the "
                          f"configuration file (file, program): {bad}")

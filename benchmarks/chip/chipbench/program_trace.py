@@ -10,7 +10,11 @@ they read the buffer of the process's newest recorder
 traced window on the host's clock (``run.trace.t0``, ``t1``):
 
 - per engine step that did work, the host time outside its waits;
-- the host time of each kind of span, outside the spans nested in it.
+- the host time of each kind of span, outside the spans nested in it;
+- the device time of a program's runs (the first chip's ``XLA Modules``
+  events, ``run.modules``, inside the traced window on the trace's
+  clock) over a count its dispatch spans carry: decode steps of a
+  decode chunk, prompt tokens of a prefill chunk.
 
 A program that records no spans (no ``repro.serve.spans``) gives None,
 and so does every metric read from it. The functions on plain lists do
@@ -70,6 +74,50 @@ def host_spans(spans, t0: float, t1: float) -> dict:
     return out
 
 
+# How the engine's programs are named among the trace's program runs:
+# ``jit_<function>(<fingerprint>)``, the function being the one the
+# engine jits for that dispatch kind.
+PROGRAMS = {"decode": "jit_decode_chunk", "prefill": "jit_prefill_chunk"}
+
+
+def program_runs(modules, lo: float, hi: float, program: str) -> list:
+    """Durations (ns) of the runs of ``program`` that start and end inside
+    [lo, hi], in the order they started."""
+    return [d for n, s, d in sorted(modules, key=lambda e: e[1])
+            if n.split("(", 1)[0] == program and s >= lo and s + d <= hi]
+
+
+def pair_runs(spans, t0: float, t1: float, runs: list, kind: str,
+              unit: str) -> list:
+    """[(device ns, ``unit``)]: each run paired, in order, with the
+    ``engine.dispatch`` span of ``kind`` inside [t0, t1] that enqueued
+    it. Runs and spans must agree in number."""
+    ds = sorted((s for s in _in(spans, t0, t1, "engine.dispatch")
+                 if s.attrs.get("kind") == kind), key=lambda s: s.t0)
+    if len(ds) != len(runs):
+        raise RuntimeError(f"{PROGRAMS[kind]}: the engine dispatched "
+                           f"{len(ds)} in the traced window, the trace holds "
+                           f"{len(runs)} runs of it")
+    return [(d, s.attrs[unit]) for d, s in zip(runs, ds)]
+
+
+def _program_time(run, kind: str, unit: str):
+    """Device ns of ``kind``'s program per ``unit`` in the traced window,
+    or None where nothing ran."""
+    spans = engine_spans()
+    if spans is None or run.trace is None or run.modules is None:
+        return None
+    t = run.trace
+    runs = program_runs(run.modules, t.lo, t.hi, PROGRAMS[kind])
+    pairs = pair_runs(spans, t.t0, t.t1, runs, kind, unit)
+    if not pairs:
+        return None
+    ns, units = sum(p[0] for p in pairs), sum(p[1] for p in pairs)
+    print(f"[programs] {PROGRAMS[kind]} runs={len(pairs)} device_s="
+          f"{ns / 1e9} {unit}={units}", file=sys.stderr, flush=True)
+    return ns / units
+
+
 def host_ms_mean(run):
     """Mean host time per working step of the traced window outside its
     device waits (ms). Logs the window's host time by span to standard
@@ -85,3 +133,17 @@ def host_ms_mean(run):
           f"{json.dumps(host_spans(spans, t0, t1))}", file=sys.stderr,
           flush=True)
     return sum(ms) / len(ms)
+
+
+def decode_step_ms(run):
+    """Device time of the decode chunks run in the traced window over the
+    decode steps they ran (ms a step)."""
+    ns = _program_time(run, "decode", "n_steps")
+    return None if ns is None else ns / 1e6
+
+
+def prefill_us_per_token(run):
+    """Device time of the prefill chunks run in the traced window over
+    the prompt tokens they prefilled (us a token)."""
+    ns = _program_time(run, "prefill", "tokens")
+    return None if ns is None else ns / 1e3

@@ -14,6 +14,7 @@ import re
 
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
+MODULES_LINE = "XLA Modules"
 HOST_PLANE = "/host:CPU"
 SPAN_PREFIX = "bench."
 
@@ -21,28 +22,32 @@ SPAN_PREFIX = "bench."
 def read_xplane(trace_dir: str):
     """(device ops per chip {plane: [(name, start_ns, dur_ns)]},
     host spans [(name, start_ns, dur_ns)] whose name starts with
-    ``SPAN_PREFIX``)."""
+    ``SPAN_PREFIX``, program runs per chip {plane: [(name, start_ns,
+    dur_ns)]}: one event per run of a compiled program, named
+    ``jit_<function>(<fingerprint>)``)."""
     from jax.profiler import ProfileData
     paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
                       recursive=True)
     if not paths:
         raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
     data = ProfileData.from_file(max(paths, key=os.path.getmtime))
-    ops, spans = {}, []
+    ops, spans, modules = {}, [], {}
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
-            evs = []
+            lines = {OPS_LINE: [], MODULES_LINE: []}
             for line in plane.lines:
-                if line.name == OPS_LINE:
-                    evs.extend((e.name, float(e.start_ns), float(e.duration_ns))
-                               for e in line.events)
-            ops[plane.name] = evs
+                if line.name in lines:
+                    lines[line.name].extend(
+                        (e.name, float(e.start_ns), float(e.duration_ns))
+                        for e in line.events)
+            ops[plane.name] = lines[OPS_LINE]
+            modules[plane.name] = lines[MODULES_LINE]
         elif plane.name == HOST_PLANE:
             for line in plane.lines:
                 spans.extend((e.name, float(e.start_ns), float(e.duration_ns))
                              for e in line.events
                              if e.name.startswith(SPAN_PREFIX))
-    return ops, spans
+    return ops, spans, modules
 
 
 def merged(events, lo: float, hi: float):

@@ -156,22 +156,59 @@ def conf_key(conf: dict) -> tuple:
             ("x_max", float(act["x_max"])), ("depth", int(act["depth"])))
 
 
+# keys by which a configuration file states a mechanism this reference
+# does not compute, with the value that says it is absent
+NOT_DENSE = {"num_local_experts": (0, 1), "num_experts": (0, 1),
+             "shared_expert_intermediate_size": (0,),
+             "sliding_window": (None,), "attention_bias": (False,),
+             "mamba_d_state": (0,), "mamba_d_conv": (0,), "mamba_expand": (0,),
+             "mamba_dt_rank": (0,), "layer_types": (None,),
+             "attn_layer_period": (None, 1), "gated_ffn": (True,),
+             "num_codebooks": (1,)}
+
+
 def check_shapes(w, conf: dict):
+    """Refuses a configuration that states a mechanism outside this
+    reference (experts, a window, a bias, Mamba layers, a layer
+    pattern, an FFN without a gate, several codebooks), and a weight tree with any leaf but the dense decoder's,
+    or with one of another shape than the file's sizes give."""
+    stated = {k: conf[k] for k, absent in NOT_DENSE.items()
+              if k in conf and conf[k] not in absent}
+    if stated:
+        raise ValueError(f"the dense reference does not compute what the "
+                         f"configuration states: {stated}")
     d, f, L = (conf["hidden_size"], conf["intermediate_size"],
                conf["num_hidden_layers"])
     H, KV, hd = (conf["num_attention_heads"], conf["num_key_value_heads"],
                  conf["head_dim"])
     want = {("blocks", "attn", "wq"): (L, d, H, hd),
             ("blocks", "attn", "wk"): (L, d, KV, hd),
+            ("blocks", "attn", "wv"): (L, d, KV, hd),
             ("blocks", "attn", "wo"): (L, H, hd, d),
             ("blocks", "ffn", "w_gate"): (L, d, f),
-            ("blocks", "ffn", "w_down"): (L, f, d)}
+            ("blocks", "ffn", "w_up"): (L, d, f),
+            ("blocks", "ffn", "w_down"): (L, f, d),
+            ("embed",): None, ("lm_head",): None}
+    if conf["qk_norm"]:
+        want.update({("blocks", "attn", "q_norm"): (L, hd),
+                     ("blocks", "attn", "k_norm"): (L, hd)})
+    if conf["norm"] == "rmsnorm":
+        want.update({("blocks", "ln1", "scale"): (L, d),
+                     ("blocks", "ln2", "scale"): (L, d),
+                     ("ln_f", "scale"): (d,)})
+    have = {tuple(str(getattr(k, "key", k)) for k in p): tuple(a.shape)
+            for p, a in jax.tree_util.tree_flatten_with_path(w)[0]}
+    extra = sorted("/".join(p) for p in set(have) - set(want))
+    missing = sorted("/".join(p) for p in set(want) - set(have))
+    if extra or missing:
+        raise ValueError(f"the dense reference computes no leaf {extra} "
+                         f"and lacks {missing}")
     for path, shape in want.items():
-        a = w
-        for k in path:
-            a = a[k]
-        if tuple(a.shape) != shape:
+        if shape is not None and have[path] != shape:
             raise ValueError(f"reference weights {'/'.join(path)} have shape "
-                             f"{a.shape}, the configuration says {shape}")
-    if w["lm_head"].shape[0] != d or w["lm_head"].shape[1] < conf["vocab_size"]:
-        raise ValueError(f"lm_head shape {w['lm_head'].shape}")
+                             f"{have[path]}, the configuration says {shape}")
+    V = conf["vocab_size"]
+    if have[("lm_head",)][0] != d or have[("lm_head",)][1] < V \
+            or have[("embed",)][1] != d or have[("embed",)][0] < V:
+        raise ValueError(f"embed {have[('embed',)]} / lm_head "
+                         f"{have[('lm_head',)]} do not fit d={d}, V={V}")
