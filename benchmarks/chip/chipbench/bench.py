@@ -524,13 +524,14 @@ def judge(conf: dict, mix: dict, finished: list, seed: int, root,
     control ranks first at the served positions, in the program's place:
     the same comparison has to find them not correct."""
     import gc
-    import jax
     gc.collect()
     from . import program, weights
     ref = check.reference_module(conf, root)
     cfg = program.model_config(conf)
+    # the fill as the program serves it: the reference widens one layer
+    # at a time, so the check holds the served tree and one layer in
+    # float32, not a float32 copy of the whole tree beside it
     w = weights.fill(program.param_shapes(cfg), seed)
-    w = jax.tree.map(lambda a: a.astype(np.float32), w)
     ref.check_shapes(w, conf)
     reqs = check.sample(finished, seed, int(mix["check"]["requests"]))
     rows = check.gaps(ref, w, conf, reqs, control=control,

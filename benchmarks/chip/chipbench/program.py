@@ -10,8 +10,10 @@ the engine's public surface: ``submit``, ``step``, ``sched.slots``,
 """
 from __future__ import annotations
 
+import itertools
 import sys
 
+from . import flops
 from .files import REPO
 
 if str(REPO / "src") not in sys.path:
@@ -27,8 +29,13 @@ def model_config(conf: dict):
     dense value (no experts, no window, no bias, no Mamba layers, a gated
     FFN, one codebook), so a
     program that has the mechanism fails under a file that does not
-    state it. ``program.fields`` ({ModelConfig attribute: value}) names
-    any further attribute to hold to a value, read by ``getattr``."""
+    state it. ``rope_theta`` is compared only where the program rotates
+    (a RoPE-less program reads None, so its file leaves the key out).
+    The file's layer pattern (``flops.layer_kinds``, which the FLOP count
+    follows) is compared with the one the program runs
+    (``program_layer_kinds``). ``program.fields`` ({ModelConfig
+    attribute: value}) names any further attribute to hold to a value,
+    read by ``getattr``."""
     from repro.configs import registry
     from repro.configs.common import fused_of
     prog = conf["program"]
@@ -37,13 +44,15 @@ def model_config(conf: dict):
         cfg = fused_of(cfg)
     act = conf["activation"]
     moe = cfg.n_experts > 0
-    mamba = cfg.use_mamba or cfg.parallel_mamba
+    kinds = program_layer_kinds(cfg)
+    mamba = any(k in ("mamba", "hybrid") for k in kinds)
     have = {
         "hidden_size": cfg.d_model, "intermediate_size": cfg.d_ff,
         "num_hidden_layers": cfg.n_layers,
         "num_attention_heads": cfg.n_heads,
         "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim_,
-        "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
+        "vocab_size": cfg.vocab_size,
+        "rope_theta": None if cfg.rope_kind == "none" else cfg.rope_theta,
         "norm": cfg.norm, "qk_norm": cfg.qk_norm,
         "hidden_act": cfg.mlp_act, "compute_dtype": cfg.compute_dtype,
         "activation.impl": cfg.activation.impl,
@@ -78,6 +87,9 @@ def model_config(conf: dict):
     for k, v in dense.items():
         want.setdefault(k, v)
     bad = {k: (want.get(k), v) for k, v in have.items() if want.get(k) != v}
+    file_kinds = flops.layer_kinds(conf)
+    if file_kinds != kinds:
+        bad["layer pattern"] = (runs(file_kinds), runs(kinds))
     for k, v in prog.get("fields", {}).items():
         got = getattr(cfg, k, "<no such attribute>")
         if got != v:
@@ -86,6 +98,35 @@ def model_config(conf: dict):
         raise ValueError(f"program config {cfg.name} differs from the "
                          f"configuration file (file, program): {bad}")
     return cfg
+
+
+def program_layer_kinds(cfg) -> list[str]:
+    """The kind of each layer the program runs (``full``, ``sliding``,
+    ``mamba`` or ``hybrid``, as ``flops.KINDS`` names them): the
+    config's own ``layer_kinds`` where a patterned program exposes it;
+    else one kind for the whole stack, as the program builds it: Mamba
+    mixers under ``use_mamba``, attention and Mamba side by side under
+    ``parallel_mamba``, windowed attention where ``sliding_window`` is
+    set, and full attention otherwise."""
+    kinds = getattr(cfg, "layer_kinds", None)
+    if kinds is not None:
+        return list(kinds)
+    if cfg.use_mamba:
+        kind = "mamba"
+    elif cfg.parallel_mamba:
+        kind = "hybrid"
+    else:
+        kind = "sliding" if cfg.sliding_window else "full"
+    return [kind] * cfg.n_layers
+
+
+def runs(kinds: list[str]) -> str:
+    """A layer pattern in runs of one kind: 'mamba*7 full mamba*6'."""
+    out = []
+    for kind, group in itertools.groupby(kinds):
+        n = len(list(group))
+        out.append(f"{kind}*{n}" if n > 1 else kind)
+    return " ".join(out)
 
 
 def make_params(cfg, seed: int):

@@ -12,8 +12,8 @@ traced window on the host's clock (``run.trace.t0``, ``t1``):
 - per engine step that did work, the host time outside its waits;
 - the host time of each kind of span, outside the spans nested in it;
 - the device time of a program's runs (the first chip's ``XLA Modules``
-  events, ``run.modules``, inside the traced window on the trace's
-  clock) over a count its dispatch spans carry: decode steps of a
+  events, ``run.modules``, each paired in order with the dispatch span
+  that enqueued it) over a count its dispatch spans carry: decode steps of a
   decode chunk, prompt tokens of a prefill chunk.
 
 A program that records no spans (no ``repro.serve.spans``) gives None,
@@ -80,25 +80,43 @@ def host_spans(spans, t0: float, t1: float) -> dict:
 PROGRAMS = {"decode": "jit_decode_chunk", "prefill": "jit_prefill_chunk"}
 
 
-def program_runs(modules, lo: float, hi: float, program: str) -> list:
-    """Durations (ns) of the runs of ``program`` that start and end inside
-    [lo, hi], in the order they started."""
-    return [d for n, s, d in sorted(modules, key=lambda e: e[1])
-            if n.split("(", 1)[0] == program and s >= lo and s + d <= hi]
+def program_runs(modules, program: str) -> list:
+    """(start, duration) in ns of every run of ``program`` in the trace,
+    in the order they started."""
+    return [(s, d) for n, s, d in sorted(modules, key=lambda e: e[1])
+            if n.split("(", 1)[0] == program]
 
 
-def pair_runs(spans, t0: float, t1: float, runs: list, kind: str,
-              unit: str) -> list:
-    """[(device ns, ``unit``)]: each run paired, in order, with the
-    ``engine.dispatch`` span of ``kind`` inside [t0, t1] that enqueued
-    it. Runs and spans must agree in number."""
-    ds = sorted((s for s in _in(spans, t0, t1, "engine.dispatch")
-                 if s.attrs.get("kind") == kind), key=lambda s: s.t0)
-    if len(ds) != len(runs):
-        raise RuntimeError(f"{PROGRAMS[kind]}: the engine dispatched "
-                           f"{len(ds)} in the traced window, the trace holds "
-                           f"{len(runs)} runs of it")
-    return [(d, s.attrs[unit]) for d, s in zip(runs, ds)]
+def pair_runs(spans, t, runs: list, kind: str, unit: str) -> list:
+    """[(device ns, ``unit``)]: each ``engine.dispatch`` span of ``kind``
+    inside the traced window [t.t0, t.t1] (a span that names another
+    ``program`` enqueued another one) paired with the run it enqueued.
+
+    The pairing is by order, and compares no clock of the device with
+    the host's: the harness syncs the device before it starts the trace,
+    so the trace's first run of the program is the one the first
+    dispatch after ``t.t0`` enqueued, and one chip runs a program's
+    dispatches in the order they came. The window's n dispatches are the
+    first n since ``t.t0``, so their runs are the trace's first n. As a
+    check on that count, the n-th run starts before the end mark
+    ``t.hi`` and the run after it, if any, ends after that mark: the
+    harness syncs the device before the mark, so the window's runs have
+    ended by it and the next one starts after it, each by a whole run's
+    length, far more than the clocks' alignment can shift it."""
+    program = PROGRAMS[kind]
+    ds = sorted((s for s in spans if s.name == "engine.dispatch"
+                 and s.t0 >= t.t0 and s.attrs.get("kind") == kind
+                 and "jit_" + s.attrs.get("program", program[4:]) == program),
+                key=lambda s: s.t0)
+    window = [s for s in ds if s.t1 <= t.t1]
+    n = len(window)
+    if not n <= len(runs) <= len(ds) or (
+            n and runs[n - 1][0] >= t.hi) or (
+            len(runs) > n and sum(runs[n]) <= t.hi):
+        raise RuntimeError(f"{program}: the engine dispatched {n} in the "
+                           f"traced window and {len(ds)} since it began, the "
+                           f"trace holds {len(runs)} runs of it")
+    return [(d, s.attrs[unit]) for (_, d), s in zip(runs, window)]
 
 
 def _program_time(run, kind: str, unit: str):
@@ -108,14 +126,31 @@ def _program_time(run, kind: str, unit: str):
     if spans is None or run.trace is None or run.modules is None:
         return None
     t = run.trace
-    runs = program_runs(run.modules, t.lo, t.hi, PROGRAMS[kind])
-    pairs = pair_runs(spans, t.t0, t.t1, runs, kind, unit)
+    runs = program_runs(run.modules, PROGRAMS[kind])
+    try:
+        pairs = pair_runs(spans, t, runs, kind, unit)
+    except RuntimeError:
+        _log_edges(runs, t, PROGRAMS[kind])
+        raise
     if not pairs:
         return None
     ns, units = sum(p[0] for p in pairs), sum(p[1] for p in pairs)
     print(f"[programs] {PROGRAMS[kind]} runs={len(pairs)} device_s="
           f"{ns / 1e9} {unit}={units}", file=sys.stderr, flush=True)
     return ns / units
+
+
+def _log_edges(runs, t, program: str):
+    """The runs of ``program`` nearest the traced window's two marks, as
+    (start, end) in ms from the mark, for reading a count that differs."""
+    mine = [(s, s + d) for s, d in runs]
+    ms = lambda pairs, at: [(round((a - at) / 1e6, 4), round((b - at) / 1e6, 4))
+                            for a, b in pairs]
+    first = mine[:3]
+    last = [p for p in mine if p[0] < t.hi][-3:] + \
+        [p for p in mine if p[0] >= t.hi][:1]
+    print(f"[programs] {program} at the start mark {ms(first, t.lo)}, at "
+          f"the end mark {ms(last, t.hi)}", file=sys.stderr, flush=True)
 
 
 def host_ms_mean(run):

@@ -3,9 +3,9 @@
 The fill runs over the shapes of the model's parameter tree (as
 ``jax.eval_shape`` of its init gives them) and draws every leaf at the
 scale the init uses, by the leaf's name. The same seed gives the same
-values to the program (in its serving dtype) and to the reference (the
-same values, widened to float32), so the reference needs nothing the
-program made.
+values to the program and to the reference, both in the serving dtype
+(the reference widens each layer to float32 as it reads it), so the
+reference needs nothing the program made.
 """
 from __future__ import annotations
 

@@ -18,8 +18,13 @@ configuration's activation: x * sigmoid(x) with sigmoid(x) =
 and saturated at tanh(x_max) (paper, Eq. 2 and 3).
 
 It imports nothing of the program and takes no table from it. Weights
-are the benchmark's own (``chipbench.weights``), widened to float32;
-matrix products run at ``highest`` precision. ``control=True`` computes
+are the benchmark's own (``chipbench.weights``) in the dtype they are
+served in; the reference widens to float32 only what it uses at once:
+each layer's leaves inside the layer scan, the embedding rows it
+gathers, and the head in ``logits_at``. bfloat16 widens exactly, so
+every product sees the values of a tree widened whole, while the
+check's peak holds the served tree and one layer in float32. Matrix
+products run at ``highest`` precision. ``control=True`` computes
 every weight product with both operands rounded to float8 (e4m3, one
 scale per output channel of the weight and per row of the activation):
 the lower precision that a later change could be tempted by.
@@ -47,7 +52,24 @@ def spline_windows(x_max: float, depth: int) -> np.ndarray:
     return knots[idx].astype(np.float32)
 
 
+# elements of the spline's input taken at once: each element's four
+# control points lie on a minor axis of 4, which the TPU pads to 128
+# lanes, so one block's gather takes 512 bytes an element (512 MiB here)
+SPLINE_BLOCK = 1 << 20
+
+
 def tanh_cr(v, windows, x_max: float, depth: int):
+    """The spline at every element of ``v`` [rows, n], a block of rows at
+    a time (every element's arithmetic as in one call over all rows: at
+    4096 rows of 8192 the gather alone would take 16 GiB)."""
+    rows = max(1, SPLINE_BLOCK // v.shape[-1])
+    if v.shape[0] <= rows:
+        return _tanh_cr(v, windows, x_max, depth)
+    return jax.lax.map(lambda r: _tanh_cr(r, windows, x_max, depth), v,
+                       batch_size=rows)
+
+
+def _tanh_cr(v, windows, x_max: float, depth: int):
     period = x_max / depth
     av = jnp.abs(v)
     u = av / period
@@ -78,6 +100,15 @@ def _mm(x, w, control: bool):
     return jnp.matmul(x, w2, precision=HI).reshape((x.shape[0],) + w.shape[1:])
 
 
+def widen(tree):
+    """Every leaf of ``tree`` in float32. The barrier keeps the widened
+    copy whole, so XLA cannot fold the widening into a product: each
+    product reads a float32 operand, as from a tree widened before the
+    call."""
+    return jax.lax.optimization_barrier(
+        jax.tree.map(lambda a: a.astype(jnp.float32), tree))
+
+
 def _norm(x, scale, kind):
     if kind == "rmsnorm":
         return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS) * scale
@@ -97,7 +128,11 @@ def _rope(x, pos, theta):
 
 @functools.partial(jax.jit, static_argnames=("conf_key", "control"))
 def hidden(w, tokens, *, conf_key, control=False):
-    """Final-norm hidden states [S, d] of one sequence ``tokens`` [S]."""
+    """Final-norm hidden states [S, d] of one sequence ``tokens`` [S].
+
+    ``w`` is the fill in its own dtype (bfloat16 as served, or float32);
+    each layer's slice is widened inside the scan, so no float32 copy of
+    the whole tree is ever made."""
     conf = dict(conf_key)
     kind, theta = conf["norm"], conf["rope_theta"]
     H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
@@ -106,10 +141,12 @@ def hidden(w, tokens, *, conf_key, control=False):
     S = tokens.shape[0]
     pos = jnp.arange(S)
     causal = pos[None, :] <= pos[:, None]
-    emb = _f8(w["embed"], -1) if control else w["embed"]
-    x = emb[tokens]
+    x = widen(w["embed"][tokens])
+    if control:
+        x = _f8(x, -1)             # one scale per row: as over the whole table
 
     def layer(x, p):
+        p = widen(p)
         a = p["attn"]
         h = _norm(x, p["ln1"].get("scale"), kind)
         q, k, v = _mm(h, a["wq"], control), _mm(h, a["wk"], control), \
@@ -133,14 +170,14 @@ def hidden(w, tokens, *, conf_key, control=False):
         return x, None
 
     x, _ = jax.lax.scan(layer, x, w["blocks"])
-    return _norm(x, w["ln_f"].get("scale"), kind)
+    return _norm(x, widen(w["ln_f"]).get("scale"), kind)
 
 
 @functools.partial(jax.jit, static_argnames=("control",))
 def logits_at(w, h, rows, *, control=False):
-    """Logits [n, V] at hidden rows ``rows``."""
+    """Logits [n, V] at hidden rows ``rows``, the head widened here."""
     hr = h[rows]
-    head = w["lm_head"]
+    head = widen(w["lm_head"])
     if control:
         hr, head = _f8(hr, -1), _f8(head, 0)
     return jnp.matmul(hr, head, precision=HI)
@@ -170,8 +207,12 @@ NOT_DENSE = {"num_local_experts": (0, 1), "num_experts": (0, 1),
 def check_shapes(w, conf: dict):
     """Refuses a configuration that states a mechanism outside this
     reference (experts, a window, a bias, Mamba layers, a layer
-    pattern, an FFN without a gate, several codebooks), and a weight tree with any leaf but the dense decoder's,
-    or with one of another shape than the file's sizes give."""
+    pattern, an FFN without a gate, several codebooks), and a weight
+    tree with any leaf but the dense decoder's, or with one of another
+    shape than the file's sizes give.
+
+    The tree is the fill as the program serves it (bfloat16): a
+    reference receives the fill's dtype and widens what it uses."""
     stated = {k: conf[k] for k, absent in NOT_DENSE.items()
               if k in conf and conf[k] not in absent}
     if stated:

@@ -12,7 +12,8 @@ written from the published description and the configuration file:
                      p_e (silu(h Wg[e]) * (h Wu[e])) Wd[e]
     logits = rmsnorm(x) W_head
 
-silu, the norms, RoPE, the float8 control and the head are the dense
+silu, the norms, RoPE, the float8 control, the head and the widening of
+the served bfloat16 fill to float32 layer by layer are the dense
 reference's (``dense.py`` beside this file). Every expert is computed
 for every token and weighted by zero where it is not chosen: plain, and
 small enough at the sizes a test runs.
@@ -47,8 +48,9 @@ def hidden(w, tokens, *, conf_key, control=False):
     pos = jnp.arange(S)
     diff = pos[:, None] - pos[None, :]
     visible = (diff >= 0) & (diff < W)
-    emb = dense._f8(w["embed"], -1) if control else w["embed"]
-    x = emb[tokens]
+    x = dense.widen(w["embed"][tokens])
+    if control:
+        x = dense._f8(x, -1)
     mm = functools.partial(dense._mm, control=control)
 
     def silu(g):
@@ -56,6 +58,7 @@ def hidden(w, tokens, *, conf_key, control=False):
                                               conf["depth"]))
 
     def layer(x, p):
+        p = dense.widen(p)
         a = p["attn"]
         h = dense._norm(x, p["ln1"]["scale"], "rmsnorm")
         q, k, v = mm(h, a["wq"]), mm(h, a["wk"]), mm(h, a["wv"])
@@ -79,7 +82,7 @@ def hidden(w, tokens, *, conf_key, control=False):
         return x + y, None
 
     x, _ = jax.lax.scan(layer, x, w["blocks"])
-    return dense._norm(x, w["ln_f"]["scale"], "rmsnorm")
+    return dense._norm(x, dense.widen(w["ln_f"])["scale"], "rmsnorm")
 
 
 def conf_key(conf: dict) -> tuple:
@@ -95,7 +98,8 @@ def conf_key(conf: dict) -> tuple:
 
 def check_shapes(w, conf: dict):
     """Refuses a tree with another leaf set or shape than this reference
-    computes."""
+    computes. The tree comes in the fill's dtype; ``hidden`` widens each
+    layer as it reads it."""
     d, f, L = (conf["hidden_size"], conf["intermediate_size"],
                conf["num_hidden_layers"])
     H, KV, hd, E = (conf["num_attention_heads"], conf["num_key_value_heads"],

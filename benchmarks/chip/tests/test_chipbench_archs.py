@@ -4,6 +4,7 @@ FLOP count by layer kind, the configuration check that states experts,
 windows and Mamba sizes, and the dense reference refusing what it does
 not compute. The two shipped configurations keep the parent's fills and
 FLOP counts exactly (pinned)."""
+import dataclasses
 import hashlib
 import math
 import sys
@@ -224,12 +225,16 @@ def _file(arch, **keys):
         "num_hidden_layers": cfg.n_layers,
         "num_attention_heads": cfg.n_heads,
         "num_key_value_heads": cfg.n_kv_heads, "head_dim": cfg.head_dim_,
-        "vocab_size": cfg.vocab_size, "rope_theta": cfg.rope_theta,
+        "vocab_size": cfg.vocab_size,
         "norm": cfg.norm, "qk_norm": cfg.qk_norm, "hidden_act": cfg.mlp_act,
         "compute_dtype": cfg.compute_dtype,
         "activation": {"impl": "cr", "depth": 32, "x_max": 4.0,
                        "kernel": False},
         "program": {"arch": arch, "smoke": True}}
+    if cfg.rope_kind != "none":
+        conf["rope_theta"] = cfg.rope_theta
+    if cfg.parallel_mamba:
+        conf["layer_types"] = ["hybrid"] * cfg.n_layers
     if cfg.n_experts:
         conf.update(num_local_experts=cfg.n_experts,
                     num_experts_per_tok=cfg.top_k)
@@ -287,6 +292,63 @@ def test_model_config_refuses_a_mechanism_the_program_lacks():
     with pytest.raises(ValueError, match="num_local_experts"):
         program.model_config(dict(TINY_CONF, num_local_experts=8,
                                   num_experts_per_tok=2))
+
+
+def test_a_ropeless_file_leaves_rope_theta_out():
+    """Falcon-Mamba's file, like Jamba's, has no rope_theta: it applies
+    no rotation. The check compares the key only where the program
+    rotates."""
+    conf = _file("falcon-mamba-7b")
+    assert "rope_theta" not in conf
+    assert program.model_config(conf).rope_kind == "none"
+    with pytest.raises(ValueError, match="rope_theta"):
+        program.model_config(dict(conf, rope_theta=10000.0))
+    with pytest.raises(ValueError, match="rope_theta"):
+        program.model_config({k: v for k, v in TINY_CONF.items()
+                              if k != "rope_theta"})
+
+
+def test_model_config_refuses_a_layer_pattern_the_program_does_not_run(
+        monkeypatch):
+    """A file that states Jamba's pattern (attention at layers 7, 21, ...
+    of period 14, Mamba elsewhere) under a program whose layers are all
+    Mamba: the FLOP count would follow the file, so the check refuses
+    it."""
+    cfg = _smoke("falcon-mamba-7b")
+    cfg = dataclasses.replace(cfg, n_layers=8, name="mamba-8")
+    monkeypatch.setitem(registry._DYNAMIC, "mamba-8", (cfg, cfg))
+    conf = _file("falcon-mamba-7b", num_hidden_layers=8,
+                 program={"arch": "mamba-8", "smoke": True})
+    assert program.program_layer_kinds(cfg) == ["mamba"] * 8
+    assert program.model_config(conf).n_layers == 8
+    jamba = dict(conf, attn_layer_period=14, attn_layer_offset=7)
+    assert flops.layer_kinds(jamba)[7] == "full"
+    with pytest.raises(ValueError, match="layer pattern.*"
+                       r"'mamba\*7 full', 'mamba\*8'"):
+        program.model_config(jamba)
+
+
+def test_a_patterned_program_states_its_layer_kinds(monkeypatch):
+    """A program config that exposes ``layer_kinds`` is held to the file
+    by it, layer for layer."""
+    cfg = _smoke("falcon-mamba-7b")
+    pattern = ("mamba", "full", "mamba", "full")
+
+    @dataclasses.dataclass(frozen=True)
+    class Patterned(type(cfg)):
+        layer_kinds: tuple = pattern
+
+    p = Patterned(**dict(
+        {f.name: getattr(cfg, f.name) for f in dataclasses.fields(cfg)},
+        n_layers=4, name="patterned"))
+    monkeypatch.setitem(registry._DYNAMIC, "patterned", (p, p))
+    conf = _file("falcon-mamba-7b", num_hidden_layers=4,
+                 attn_layer_period=2, attn_layer_offset=1,
+                 program={"arch": "patterned", "smoke": True})
+    assert program.program_layer_kinds(p) == list(pattern)
+    assert program.model_config(conf).name == "patterned"
+    with pytest.raises(ValueError, match="layer pattern"):
+        program.model_config(dict(conf, attn_layer_offset=0))
 
 
 def test_one_expert_routed_top_1_is_a_dense_ffn():

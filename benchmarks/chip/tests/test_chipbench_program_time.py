@@ -55,11 +55,15 @@ def _run(modules, trace):
 
 
 def test_program_runs_in_the_window():
-    _, modules, trace = _window()
-    assert pt.program_runs(modules, trace.lo, trace.hi, "jit_decode_chunk") \
-        == [40e6, 15e6]
-    assert len(pt.program_runs(modules, 0, 1e9, "jit_prefill_chunk")) == 3
-    assert pt.program_runs(modules, 0, 1e9, "jit_decode") == []
+    """Every run of a program in the trace, in the order it started; the
+    window's are the first as many as the window dispatched."""
+    spans, modules, trace = _window()
+    runs = pt.program_runs(modules, "jit_decode_chunk")
+    assert runs == [(110e6, 40e6), (510e6, 15e6), (1.2e9, 40e6)]
+    assert len(pt.program_runs(modules, "jit_prefill_chunk")) == 3
+    assert pt.program_runs(modules, "jit_decode") == []
+    assert pt.pair_runs(spans, trace, runs, "decode", "n_steps") == \
+        [(40e6, 8), (15e6, 3)]
 
 
 def test_readers_divide_device_time_by_the_spans_counts():
@@ -79,10 +83,16 @@ def test_a_count_mismatch_raises():
     spans, modules, trace = _window()
     SpanRecorder().spans.extend(spans)
     with pytest.raises(RuntimeError, match="dispatched 3 in the traced "
-                       "window, the trace holds 2 runs"):
+                       "window and 3 since it began, the trace holds 2 runs"):
         pt.prefill_us_per_token(_run(modules[:2] + modules[3:], trace))
+    # a run missing at the start: the window's second would be one that
+    # starts after the end mark
     with pytest.raises(RuntimeError, match="jit_decode_chunk"):
         pt.decode_step_ms(_run(modules[1:], trace))
+    # a run too many: the one after the window's ends before the mark
+    extra = modules + [(DECODE, 600 * 1e6, 40 * 1e6)]
+    with pytest.raises(RuntimeError, match="the trace holds 4 runs"):
+        pt.decode_step_ms(_run(extra, trace))
 
 
 def test_nothing_to_read_is_none(monkeypatch):
@@ -90,9 +100,11 @@ def test_nothing_to_read_is_none(monkeypatch):
     SpanRecorder().spans.extend(spans)
     assert pt.decode_step_ms(_run(modules, None)) is None
     assert pt.decode_step_ms(_run(None, trace)) is None
-    # a window in which the engine ran no such program
+    # a window in which the engine ran no such program (its trace begins
+    # with the window, so it holds none of the earlier runs)
     quiet = types.SimpleNamespace(lo=2e9, hi=3e9, t0=20.0, t1=21.0)
-    assert pt.prefill_us_per_token(_run(modules, quiet)) is None
+    later = [m for m in modules if m[1] >= quiet.lo]
+    assert pt.prefill_us_per_token(_run(later, quiet)) is None
     monkeypatch.setitem(sys.modules, "repro.serve.spans", None)
     assert pt.decode_step_ms(_run(modules, trace)) is None
 
@@ -130,3 +142,37 @@ def test_read_trace_keeps_the_first_chips_program_runs(tmp_path, monkeypatch):
     trace, runs = bench.read_trace(str(tmp_path), 10.0, 11.0)
     assert runs == [(DECODE, 4.0, 4.0)]
     assert (trace.lo, trace.hi, len(trace.ops)) == (1.0, 9.0, 1)
+
+
+def test_a_run_counts_by_its_start():
+    """A run that the window enqueued counts though its end reads past
+    the end mark (the device's clock is aligned with the host's only to
+    microseconds), and so does one whose start reads before the start
+    mark: runs pair with dispatches by order, not by the clocks."""
+    spans, modules, trace = _window()
+    SpanRecorder().spans.extend(spans)
+    late_end = [(n, s, d) if s != 530e6 else (n, 999.99e6, 51.2e6)
+                for n, s, d in modules]
+    assert [d for _, d in pt.program_runs(late_end, "jit_prefill_chunk")] \
+        == [51.2e6, 30e6, 51.2e6]
+    assert files.metric_reader("prefill_us_per_token.batch")(
+        _run(late_end, trace)) == pytest.approx(132.4e3 / 1324)
+    early = [(n, s - 150.01e6, d) if s == 150e6 else (n, s, d)
+             for n, s, d in modules]
+    assert files.metric_reader("prefill_us_per_token.batch")(
+        _run(early, trace)) == pytest.approx(132.4e3 / 1324)
+
+
+def test_spans_of_another_program_are_not_paired():
+    """A prefill dispatch span that names another program (a one-shot
+    ``prefill_sample``) enqueued no ``jit_prefill_chunk`` run."""
+    spans, modules, trace = _window()
+    spans.append(span("engine.dispatch", 10.8, 10.81, "engine.step",
+                      kind="prefill", program="prefill_sample", requests=2,
+                      tokens=640, bucket=512))
+    for s in spans:
+        if s.name == "engine.dispatch" and s.attrs["kind"] == "prefill":
+            s.attrs.setdefault("program", "prefill_chunk")
+    SpanRecorder().spans.extend(spans)
+    assert files.metric_reader("prefill_us_per_token.chat")(
+        _run(modules, trace)) == pytest.approx(132.4e3 / 1324)
